@@ -52,4 +52,6 @@ def main(fast: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(fast=False)

@@ -12,9 +12,11 @@ The point of the exercise: at n = 10,000 (k = 8, p = 32) the dense
 
 ``--sharded`` additionally runs the graph-partitioned engine
 (``simulate.partition``) on a mesh of ``--shards`` devices and reports the
-event-throughput ratio over the single-device run.  On a CPU-only host the
-devices are XLA fake host devices; this script force-creates them (the flag
-must precede jax init, so it is set at import time when --sharded is given).
+event-throughput ratio over the single-device run.  Under
+``JAX_PLATFORMS=cpu`` the devices are XLA fake host devices, which this
+script force-creates (the flag must precede jax init, so it is set at
+import time when --sharded is given); on an accelerator host the mesh is
+the real devices, and ``--shards`` above their count is an error.
 Each sharded run also records the per-round halo wire bytes under every
 ``HaloCodec`` and fails when int8 exceeds ``--halo-max-int8-ratio`` (0.35)
 of f32.  ``--fused`` (mp only) reruns each config through the fused
@@ -60,8 +62,12 @@ def _requested_shards(argv) -> int:
     return 8
 
 
-if "--sharded" in sys.argv and \
-        "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+# fake host devices only where the CPU is the requested platform: on a TPU
+# host the mesh is the real chips, and asking for more shards than exist
+# raises in make_sim_mesh instead of silently running fewer
+if "--sharded" in sys.argv and os.environ.get("JAX_PLATFORMS") == "cpu" \
+        and "host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                               ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count="
@@ -301,7 +307,7 @@ def main(argv=None) -> int:
                          "event-throughput ratio over one device")
     ap.add_argument("--shards", type=int, default=8,
                     help="mesh size for --sharded (forced as fake host "
-                         "devices when the process has fewer)")
+                         "devices under JAX_PLATFORMS=cpu)")
     ap.add_argument("--repeats", type=int, default=1,
                     help="timed repeats per run (min is reported)")
     ap.add_argument("--overhead", action="store_true",
@@ -493,4 +499,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -65,7 +65,7 @@ def backends_and_sweeps():
     sol = np.asarray(solitary_mean(data))
     conf = np.asarray(confidences_from_counts(data.counts))
 
-    # auto backend: fused XLA on CPU/GPU, Pallas compiled on TPU
+    # auto backend: fused XLA on every platform
     auto = synchronous(g, sol, conf, alpha=0.9, steps=300)
     # explicit override: validate the Pallas kernel via interpret mode
     # repro-lint: disable=RPL005  demo opts in to validate the kernel on CPU

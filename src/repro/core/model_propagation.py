@@ -86,8 +86,8 @@ def synchronous(graph: Graph, theta_sol, c, alpha: float, steps: int,
 
     Each iterate is one ``mix`` op — A_mix @ theta + b * theta_sol with
     A_mix = diag(alpha/(alpha+abar c)) P and b = abar c/(alpha+abar c) —
-    resolved through ``kernels.dispatch`` (fused XLA on CPU/GPU, Pallas
-    kernel on TPU, overridable per call via ``backend``).
+    resolved through ``kernels.dispatch`` (fused XLA by default,
+    overridable per call via ``backend``).
     """
     n = graph.n
     P = jnp.asarray(graph.P, jnp.float32)

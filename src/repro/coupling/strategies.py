@@ -43,18 +43,6 @@ from repro.core.graph import Graph
 from repro.kernels.dispatch import ReproBackend, resolve
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax.shard_map (new, check_vma) or
-    jax.experimental.shard_map.shard_map (jax <= 0.4.x, check_rep)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class CouplingConfig:
     mode: str = "mp"              # none | consensus | mp | cl | cl_admm
@@ -63,19 +51,17 @@ class CouplingConfig:
     mu: float = 0.01              # CL trade-off
     rho: float = 1.0              # ADMM penalty
     every: int = 1                # apply every k optimizer steps
-    use_kernel: bool = False      # deprecated: force the Pallas "mix" impl
+    use_kernel: bool = False      # deprecated: request the Pallas "mix" impl
     mix_dtype: Any = jnp.float32  # wire dtype for cross-agent traffic
     # kernels.dispatch.ReproBackend choosing the "mix" implementation
-    # (None = platform auto: Pallas compiled on TPU, fused XLA elsewhere)
+    # (None = auto: fused XLA on every platform)
     backend: Optional[ReproBackend] = None
 
     def mix_backend(self) -> Optional[ReproBackend]:
         if self.backend is not None:
             return self.backend
         if self.use_kernel:
-            return ReproBackend.using(
-                mix="pallas",
-                interpret=None if jax.default_backend() == "tpu" else True)
+            return ReproBackend.using(mix="pallas")
         return None
 
 
@@ -274,9 +260,9 @@ def make_coupling(cfg: CouplingConfig, state: CouplingState,
                     out = gossip_mix_tree(p_loc, s_loc, state, cfg, names)
                     return jax.tree_util.tree_map(lambda a: a[None], out)
 
-                mixed = _shard_map(
+                mixed = jax.shard_map(
                     body, mesh=mesh, in_specs=(specs_in, specs_in),
-                    out_specs=specs_in)(params, solitary)
+                    out_specs=specs_in, check_vma=False)(params, solitary)
                 do = (step % cfg.every) == 0
                 return jax.tree_util.tree_map(
                     lambda a, b: jnp.where(do, a, b), mixed, params)

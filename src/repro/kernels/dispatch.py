@@ -16,14 +16,19 @@ This module is the single chooser.  A registry keyed by
 maps to concrete callables; ``resolve(op, backend)`` returns the callable a
 call site should use.  Selection rules:
 
-* **auto** (the default): Pallas *compiled* on TPU, fused XLA on CPU/GPU.
-  Auto never selects an interpret-mode Pallas impl — interpret is a
-  validation tool, orders of magnitude slower than XLA, and must be
-  requested explicitly together with the impl
+* **auto** (the default): the fused XLA impl on every platform, TPU
+  included (the reference where an op has no XLA impl).  No Pallas impl is
+  an auto default: none has a chip measurement showing it beating XLA, and
+  the ``round_step``/``cl_edge_step``/``sparse_mix`` kernels keep whole
+  state arrays in VMEM, which the v5e compiler refuses at deployment
+  sizes (each kernel's docstring states the bound it compiles under).  A
+  Pallas impl becomes a default again only with a benchmark cell showing
+  it winning on the chip.  Pallas impls run when requested per op — on
+  TPU compiled, elsewhere with the explicit interpret opt-in
   (``ReproBackend.using(interpret=True, <op>="pallas")``).  Impls
-  registered ``interpret_only=True`` (e.g. the ``admm_edge`` Pallas kernel,
-  ~36x slower than its fused-XLA form even compiled) are additionally
-  skipped by auto on TPU and require the interpret opt-in everywhere.
+  registered ``interpret_only=True`` (the ``admm_edge`` Pallas kernel,
+  ~36x slower than its fused-XLA form even compiled) require the
+  interpret opt-in everywhere.
 * per-op **overrides** via :class:`ReproBackend`, threaded through
   ``core.model_propagation`` / ``core.collaborative`` / ``core.sparse`` /
   ``simulate.engines`` / ``coupling.strategies`` / ``models.blocks``.
@@ -84,10 +89,6 @@ from .round_fuse import (decode_slots, encode_slots,  # noqa: F401
 
 IMPLS = ("reference", "xla", "pallas", "pallas_sparse", "xla_sharded",
          "pallas_sparse_sharded")
-# Pallas impls "auto" may pick (single-device only; the sharded wrappers are
-# explicit opt-ins — they reshard their inputs, which auto must never do
-# silently).
-_PALLAS_IMPLS = ("pallas", "pallas_sparse")
 
 
 class BackendUnavailable(RuntimeError):
@@ -185,7 +186,7 @@ class ReproBackend:
     """Backend selection config threaded through the algorithm layers.
 
     default:   implementation used for every op without an override —
-               "auto" picks Pallas-compiled on TPU and fused XLA elsewhere.
+               "auto" picks fused XLA on every platform.
     overrides: per-op (op, impl) pairs, e.g. (("mix", "pallas"),).
     interpret: explicit opt-in to Pallas interpret mode off-TPU (None
                defers to the REPRO_PALLAS_INTERPRET env var; on TPU the
@@ -219,18 +220,12 @@ class ReproBackend:
 
 
 def _auto_impl(op: str) -> str:
-    """Platform default: Pallas compiled on TPU (when the op has a Pallas
-    impl that is not interpret-only), fused XLA otherwise.  Auto never
-    selects an impl that would run in interpret mode — interpret is a
-    validation tool and must be requested together with an explicit impl
-    override (tests/test_dispatch.py pins this rule)."""
-    impls = _REGISTRY[op]
-    if _platform() == "tpu":
-        name = next((n for n in _PALLAS_IMPLS
-                     if n in impls and not impls[n].interpret_only), None)
-        if name is not None:
-            return name
-    return "xla" if "xla" in impls else "reference"
+    """Platform default: the fused XLA impl on every platform (the
+    reference where an op has none).  Auto never selects a Pallas impl —
+    not compiled on TPU (no chip measurement justifies one as a default)
+    and not in interpret mode anywhere (tests/test_dispatch.py pins this
+    rule)."""
+    return "xla" if "xla" in _REGISTRY[op] else "reference"
 
 
 def available(op: str, impl: str, *, interpret: Optional[bool] = None) -> bool:

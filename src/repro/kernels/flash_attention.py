@@ -9,7 +9,9 @@ blocks via @pl.when (no MXU work issued for them).
 BlockSpec tiling (VMEM working set per grid step, bf16):
   q: (bQ, hd) + k,v: (bK, hd) + acc: (bQ, hd) f32 + p: (bQ, bK) f32
   with bQ=bK=256, hd=128: ~0.6 MB << 16 MB VMEM; MXU dims are multiples
-  of 128 (bQ, bK, hd).
+  of 128 (bQ, bK, hd).  The VMEM use does not grow with S: the v5e
+  compiler (tests/test_chip_compile.py) accepts S=1024 and S=4096 with
+  16 heads of hd=128.
 
 The GQA head expansion happens in ops.py (kv heads repeated to q heads)
 so the kernel sees equal head counts.

@@ -11,6 +11,10 @@ axis D is tiled into VMEM-resident blocks. Each grid step does one
 (n x n) @ (n x bD) MXU matmul plus a fused multiply-add — arithmetic
 intensity ~n, so the kernel is HBM-bandwidth-bound and the win over the
 unfused reference is one pass over theta/theta_sol instead of three.
+
+VMEM bound: A (n, n) stays resident beside (n, block_d) tiles, so n is the
+limit and D is not; the v5e compiler (tests/test_chip_compile.py) accepts
+n=16, D=4096 and n=256, D=65,536.
 """
 
 from __future__ import annotations

@@ -55,9 +55,13 @@ XLA's scatter (both are valid realizations of the unordered batch, and the
 id column keeps each realization self-consistent; see
 tests/test_round_fuse.py).
 
-Whole-state-in-VMEM is the operating point: the kernels size for
-``n * k * p`` f32 state within the ~16 MB VMEM budget (n=10k, k=8, p=32 is
-~10 MB).  Larger states belong to the fused-XLA impl or the sharded engines.
+Whole-state-in-VMEM is the operating point, and it bounds both kernels to
+small states.  The v5e compiler (tests/test_chip_compile.py) accepts
+``round_step_pallas`` at n=1024, k=8, p=64 and ``cl_edge_step_pallas`` at
+n=1024, k=8, p=64 (17 MB of operands); it refuses n=10,000, k=8, p=32 for
+``round_step`` (39 MB of scoped VMEM against its 16 MiB limit) and
+n=4096, k=8, p=32 for ``cl_edge_step`` (86 MB).  Larger states belong to
+the fused-XLA impl — the auto choice — or the sharded engines.
 """
 
 from __future__ import annotations
@@ -226,31 +230,31 @@ def round_step_xla(theta, Ke, got_ever, msg, tgt_row, enc, k_old,
 
 def _load_row(ref, i):
     """(X, p) ref -> row i as (p,)."""
-    return pl.load(ref, (pl.ds(i, 1), slice(None)))[0]
+    return ref[pl.ds(i, 1), :][0]
 
 
 def _load_slot(ref, i, s):
     """(X, k, p) ref -> slot (i, s) as (p,)."""
-    return pl.load(ref, (pl.ds(i, 1), pl.ds(s, 1), slice(None)))[0, 0]
+    return ref[pl.ds(i, 1), pl.ds(s, 1), :][0, 0]
 
 
 def _load_scalar(ref, i, s=None):
     """(X, 1) or (X, k) ref -> scalar at (i[, s])."""
     if s is None:
-        return pl.load(ref, (pl.ds(i, 1), slice(None)))[0, 0]
-    return pl.load(ref, (pl.ds(i, 1), pl.ds(s, 1)))[0, 0]
+        return ref[pl.ds(i, 1), :][0, 0]
+    return ref[pl.ds(i, 1), pl.ds(s, 1)][0, 0]
 
 
 def _store_row(ref, i, val):
-    pl.store(ref, (pl.ds(i, 1), slice(None)), val[None])
+    ref[pl.ds(i, 1), :] = val[None]  # scatter: unique targets
 
 
 def _store_slot(ref, i, s, val):
-    pl.store(ref, (pl.ds(i, 1), pl.ds(s, 1), slice(None)), val[None, None])
+    ref[pl.ds(i, 1), pl.ds(s, 1), :] = val[None, None]  # scatter: unique targets
 
 
 def _store_scalar(ref, i, s, val):
-    pl.store(ref, (pl.ds(i, 1), pl.ds(s, 1)), val[None, None])
+    ref[pl.ds(i, 1), pl.ds(s, 1)] = val[None, None]  # scatter: unique targets
 
 
 def _mp_round_kernel(theta_ref, ke_ref, got_ref, msg_ref, row_ref, enc_ref,

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.sim_mesh import AGENT_AXIS, make_sim_mesh, mesh_shards
-from repro.launch.sim_mesh import shard_map_1d
 
 
 def _pad_rows(x, rows: int):
@@ -56,7 +55,8 @@ def sharded_sparse_mix(table, idx, w, b, sol, *, inner: Callable, mesh=None):
         return inner(full, idx_blk, w_blk, b_blk, sol_blk)
 
     spec = P(AGENT_AXIS)
-    run = shard_map_1d(block, mesh, in_specs=(spec,) * 5, out_specs=spec)
+    run = jax.shard_map(block, mesh=mesh, check_vma=False,
+                        in_specs=(spec,) * 5, out_specs=spec)
     padded = [_pad_rows(a, rows) for a in (table, idx, w, b, sol)]
     return run(*padded)[:n]
 
@@ -81,9 +81,9 @@ def sharded_admm_primal(
         return inner(w_, lv, zo, zn, lo, ln, D_, m_, sx_, mu, rho)
 
     spec = P(AGENT_AXIS)
-    run = shard_map_1d(
-        jax.vmap(row_solve), mesh, in_specs=(spec,) * 9, out_specs=(spec, spec)
-    )
+    run = jax.shard_map(
+        jax.vmap(row_solve), mesh=mesh, check_vma=False,
+        in_specs=(spec,) * 9, out_specs=(spec, spec))
     D_pad = jnp.pad(D, (0, rows - n), constant_values=1.0)
     padded = [_pad_rows(a, rows) for a in (w, live, z_own, z_nbr, l_own, l_nbr)]
     theta, theta_js = run(*padded, D_pad, _pad_rows(m, rows), _pad_rows(sx, rows))
@@ -118,7 +118,8 @@ def sharded_admm_edge(
         return inner(*args, rho=rho)
 
     spec = P(AGENT_AXIS)
-    run = shard_map_1d(block, mesh, in_specs=(spec,) * 8, out_specs=(spec,) * 6)
+    run = jax.shard_map(block, mesh=mesh, check_vma=False,
+                        in_specs=(spec,) * 8, out_specs=(spec,) * 6)
     padded = [
         _pad_rows(a, rows)
         for a in (t_ii, t_ji, t_jj, t_ij, l_own_i, l_nbr_j_of_i, l_own_j, l_nbr_i_of_j)
@@ -143,7 +144,8 @@ def sharded_edge_reweight(d, w, live, *, eta, lam, inner: Callable, mesh=None):
         return inner(d_blk, w_blk, live_blk, eta=eta, lam=lam)
 
     spec = P(AGENT_AXIS)
-    run = shard_map_1d(block, mesh, in_specs=(spec,) * 3, out_specs=spec)
+    run = jax.shard_map(block, mesh=mesh, check_vma=False,
+                        in_specs=(spec,) * 3, out_specs=spec)
     padded = [_pad_rows(a, rows) for a in (d, w, live)]
     return run(*padded)[:n]
 
@@ -166,6 +168,7 @@ def sharded_graph_mix(theta, theta_sol, A, b, *, inner: Callable, mesh=None):
         return inner(full, sol_blk, A_blk, b_blk)
 
     spec = P(AGENT_AXIS)
-    run = shard_map_1d(block, mesh, in_specs=(spec,) * 4, out_specs=spec)
+    run = jax.shard_map(block, mesh=mesh, check_vma=False,
+                        in_specs=(spec,) * 4, out_specs=spec)
     padded = [_pad_rows(a, rows) for a in (theta, theta_sol, A_pad, b)]
     return run(*padded)[:n]

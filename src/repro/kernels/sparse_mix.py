@@ -15,6 +15,12 @@ tables with the anchor fused in.
 TPU mapping: the agent axis is tiled into blocks of ``block_n`` rows; the
 model table stays resident and is gathered row-by-row with dynamic slices
 (k_max is small — 8/16 — so the inner slot loop is fully unrolled).
+
+VMEM bound: the whole (N, p) gather table is one VMEM block.  The v5e
+compiler (tests/test_chip_compile.py) accepts N=65,536, p=64 (a 16 MiB
+table) and refuses N=200,000 (51 MB: over the 16 MiB scoped-VMEM limit)
+and N=500,000 (256 MB: over the 128 MiB of VMEM).  The sharded wrapper
+all-gathers the whole table first, so the same bound holds for N there.
 """
 
 from __future__ import annotations

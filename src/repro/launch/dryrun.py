@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ALIASES, get_config
 from repro.coupling import CouplingConfig, make_state
 from repro.core import random_geometric_graph
-from repro.launch.mesh import make_production_mesh, n_agents_of, use_mesh
+from repro.launch.mesh import make_production_mesh, n_agents_of
 from repro.launch.shapes import SHAPES, InputShape, plan_decode
 from repro.launch.sharding import (agent_axes_of, stacked_param_specs,
                                    batch_specs, stacked_cache_specs, named)
@@ -216,9 +216,9 @@ def _measure(cfg_v, shape, mesh, mode, schedule, coupling, every=1,
         jitted, args, _ = build_prefill(cfg_v, shape, mesh)
     else:
         jitted, args, _ = build_decode(cfg_v, shape, mesh, lockstep=lockstep)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
-    cost = ha.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = ha.collective_stats(compiled.as_text())
     vec = {"flops": float(cost.get("flops", 0.0)),
            "bytes": float(cost.get("bytes accessed", 0.0))}
@@ -321,7 +321,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, schedule: str,
     rec["param_count"] = model.param_count()
     rec["active_params"] = active_param_count(cfg, model)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         rec["lower_s"] = round(time.time() - t0, 1)
         t1 = time.time()
@@ -336,7 +336,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, schedule: str,
         v = getattr(mem, attr, None)
         if v is not None:
             rec[attr] = int(v)
-    cost = ha.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     # raw (scanned) numbers — under-report loop bodies; kept for reference
     rec["scanned_flops"] = float(cost.get("flops", 0.0))
     rec["scanned_bytes"] = float(cost.get("bytes accessed", 0.0))

@@ -30,17 +30,6 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
-def cost_dict(compiled) -> dict:
-    """Version-compat accessor for Compiled.cost_analysis().
-
-    Newer jax returns a flat dict; jax <= 0.4.x returns a one-element list
-    of dicts (and some backends return None).
-    """
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return c or {}
-
 # ring-algorithm wire factor per unit of *result* bytes
 _WIRE_FACTOR = {
     "all-gather": 1.0,          # each device receives (n-1)/n of the result

@@ -9,35 +9,26 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # Auto axes: the models place arrays with with_sharding_constraint,
+    # which jax.make_mesh's default Explicit axes turn into an assertion
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_agents: int = 4, model: int = 2, *,
                     multi_pod: bool = False):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
     if multi_pod:
-        return jax.make_mesh((2, n_agents, model), ("pod", "data", "model"))
-    return jax.make_mesh((n_agents, model), ("data", "model"))
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Compat shim across jax versions: ``jax.set_mesh`` (new), else
-    ``jax.sharding.use_mesh``, else the Mesh object's own context manager
-    (the only spelling on jax <= 0.4.x).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    sharding_use = getattr(jax.sharding, "use_mesh", None)
-    if sharding_use is not None:
-        return sharding_use(mesh)
-    return mesh
+        return _auto_mesh((2, n_agents, model), ("pod", "data", "model"))
+    return _auto_mesh((n_agents, model), ("data", "model"))
 
 
 def n_agents_of(mesh) -> int:

@@ -14,8 +14,12 @@ On a CPU-only host, multi-device runs use XLA's fake host devices::
 
 The flag must be set before the first jax call in the process (jax locks
 the device count on first init), which is why the helpers here never force
-a device count themselves — they size the mesh to whatever the process
-already has.
+a device count themselves — a mesh larger than the process's device count
+is an error, never silently shrunk.
+
+The engines run their per-shard programs under ``jax.shard_map`` with
+``check_vma=False``: those programs mix replicated event streams with
+sharded state and gather/ppermute collectives the checker cannot type.
 
 Defined as functions so importing this module never touches jax device
 state (same rule as ``launch.mesh``).
@@ -48,14 +52,23 @@ def max_shards() -> int:
 def make_sim_mesh(n_shards: Optional[int] = None):
     """1-D mesh over ``n_shards`` devices (default: all local devices).
 
-    ``n_shards`` is clamped to the available device count so callers can
-    ask for the "ideal" P and degrade gracefully on smaller hosts (a
-    single-device process gets a P = 1 mesh, on which the sharded engines
-    reduce to the plain sparse path).
+    Raises ``ValueError`` when ``n_shards`` is not in ``[1, device
+    count]``: a mesh smaller than asked for would silently change what a
+    sharded run measures.
     """
     avail = max_shards()
-    n = avail if n_shards is None else max(1, min(n_shards, avail))
-    return jax.make_mesh((n,), (AGENT_AXIS,))
+    n = avail if n_shards is None else int(n_shards)
+    if not 1 <= n <= avail:
+        raise ValueError(
+            f"asked for a {n}-shard sim mesh but this process has {avail} "
+            f"device(s); on a CPU-only host start the process with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+    # Auto axis: the engines place data with shard_map specs and
+    # device_put; jax.make_mesh's default Explicit axis would type every
+    # shard_map output as sharded and refuse the uneven row slices the
+    # sharded dispatch impls take of them
+    return jax.make_mesh((n,), (AGENT_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def mesh_shards(mesh) -> int:
@@ -236,24 +249,3 @@ def shard_read_route(owner, local_pos, users):
     users = np.asarray(users, np.int64)
     return (np.asarray(owner, np.int32)[users],
             np.asarray(local_pos, np.int32)[users])
-
-
-def shard_map_1d(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map over a sim mesh.
-
-    ``jax.shard_map`` (new API, ``check_vma``) when present, else
-    ``jax.experimental.shard_map.shard_map`` (jax <= 0.4.x, ``check_rep``).
-    Replication checking is disabled in both spellings: the simulator's
-    per-shard programs mix replicated event streams with sharded state and
-    gather/ppermute collectives the checker cannot type.
-    """
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, check_vma=False, **kw)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    return sm_old(f, check_rep=False, **kw)

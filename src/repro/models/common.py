@@ -329,15 +329,9 @@ def constrain(x, spec: P):
     spec = _resolve_agent_slot(spec)
     try:
         mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return jax.lax.with_sharding_constraint(
-                x, adapt_pspec(spec, tuple(mesh.axis_names)))
-        from jax.interpreters import pxla  # legacy `with mesh:` context
-        pm = pxla.thread_resources.env.physical_mesh
-        if pm.empty:
+        if mesh is None or mesh.empty:
             return x
         return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(
-                pm, adapt_pspec(spec, tuple(pm.axis_names))))
-    except (ValueError, RuntimeError, AttributeError):
+            x, adapt_pspec(spec, tuple(mesh.axis_names)))
+    except (ValueError, RuntimeError):
         return x

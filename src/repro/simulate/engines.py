@@ -163,10 +163,11 @@ def sparse_sync_mp(topo: SparseTopology, theta_sol, c, alpha: float,
                       + (1-alpha) c_i theta_sol[i]) / (alpha + (1-alpha) c_i)
 
     One sweep = one "sparse_mix" op (O(n * k * p) gather-mix over all
-    agents), resolved through ``kernels.dispatch``: fused XLA take/einsum on
-    CPU/GPU, the Pallas gather kernel on TPU.  ``use_kernel=True`` is the
-    deprecated spelling of ``backend=ReproBackend.using(
-    sparse_mix="pallas_sparse", interpret=<off-TPU>)``.
+    agents), resolved through ``kernels.dispatch`` (fused XLA take/einsum
+    by default).  ``use_kernel=True`` is the deprecated spelling of
+    ``backend=ReproBackend.using(sparse_mix="pallas_sparse")``; off-TPU it
+    needs the interpret opt-in (``REPRO_PALLAS_INTERPRET=1``) like any
+    explicit Pallas request.
     """
     from repro.core.model_propagation import mp_mix_operator
     tabs = topo.device_tables()
@@ -177,9 +178,7 @@ def sparse_sync_mp(topo: SparseTopology, theta_sol, c, alpha: float,
     w, b = mp_mix_operator(tabs.nbr_p, c, alpha)
 
     if use_kernel and backend is None:
-        backend = ReproBackend.using(
-            sparse_mix="pallas_sparse",
-            interpret=None if jax.default_backend() == "tpu" else True)
+        backend = ReproBackend.using(sparse_mix="pallas_sparse")
     mix = resolve("sparse_mix", backend)
 
     def sweep(theta, _):
@@ -227,7 +226,7 @@ class SimTrace:
 @partial(jax.jit, static_argnames=("conditions", "alpha", "batch",
                                    "record_every", "n_rec", "tel",
                                    "backend"))
-def _scenario_scan(tabs, part_half, rates, theta_sol, c, carry0, keys, ts, *,
+def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
                    conditions: NetworkConditions, alpha: float, batch: int,
                    record_every: int, n_rec: int, tel: bool = False,
                    backend: Optional[ReproBackend] = None):
@@ -245,28 +244,29 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, carry0, keys, ts, *,
     (kernels/round_fuse.py): the carry threads the flat slot table, the
     software-pipelined prefetch of the *next* round's events/operands
     (drawn at the end of each round, after its scatters), and the per-row
-    first-receipt flags.  The caller passes ``carry0=None`` and the plain
-    unshifted keys; the fused carry is built here, in-jit, and the keys
-    are shifted one round ahead internally so the carried prefetch
+    first-receipt flags.  The caller passes the plain unshifted keys; the
+    keys are shifted one round ahead internally so the carried prefetch
     consumes the bitwise-identical RNG stream.  At the default None the
-    traced program is the historic per-op gather/mix/scatter sequence,
-    unchanged."""
+    traced program is the historic per-op gather/mix/scatter sequence.
+
+    Both round bodies build their carry (warm start included) here, in-jit,
+    from ``theta_sol``: the ~n*k*p slot table is then a temporary the scan
+    updates in place, never an argument buffer it would have to copy."""
     n = theta_sol.shape[0]
     fused = backend is not None
     step_fn = resolve("round_step", backend) if fused else None
+    theta0, K0 = _mp_warm_start(tabs, theta_sol)
     if fused:
         km = tabs.nbr_idx.shape[1]
         no_stale = conditions.stale_prob == 0.0
         a_w = round_scales(tabs.nbr_p, c, alpha=alpha)
-        theta_base = batched_model_update(
-            tabs.nbr_p, theta_sol[tabs.nbr_idx], c, theta_sol, alpha)
-    if fused and carry0 is None:
-        # build the fused carry in-jit (warm start, slot table, round 0's
-        # prefetch from the unshifted first key, first-receipt flags); the
-        # scan then consumes the keys shifted one round ahead (the last
-        # key's second draw is discarded), so the carried prefetch sees
-        # the bitwise-identical RNG stream
-        theta0, K0 = _mp_warm_start(tabs, theta_sol)
+        theta_base = batched_model_update(tabs.nbr_p, K0, c, theta_sol,
+                                          alpha)
+        # build the fused carry (slot table, round 0's prefetch from the
+        # unshifted first key, first-receipt flags); the scan then consumes
+        # the keys shifted one round ahead (the last key's second draw is
+        # discarded), so the carried prefetch sees the bitwise-identical
+        # RNG stream
         active0 = jnp.ones((n,), bool)
         Ke0 = encode_slots(K0)
         flat = keys.reshape(-1, 2)
@@ -281,9 +281,12 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, carry0, keys, ts, *,
             n_rec, record_every, 2)
         carry0 = (theta0, Ke0, pf0, active0, jnp.int32(0), jnp.int32(0),
                   jnp.int32(0), jnp.zeros((n,), bool))
-        if tel:
-            carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
-                               jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    else:
+        carry0 = (theta0, K0, theta0, jnp.ones((n,), bool),
+                  jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    if tel:
+        carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
+                           jnp.int32(0), jnp.int32(0), jnp.int32(0))
 
     def round_fn(carry, inp):
         t, key = inp
@@ -397,7 +400,10 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, carry0, keys, ts, *,
                            d_link, d_churn, d_part, carry[6])
         return carry, (carry[0], frac)
 
-    return jax.lax.scan(outer, carry0, (keys, ts))
+    carry, outs = jax.lax.scan(outer, carry0, (keys, ts))
+    # only the event counters leave the scan: returning the final slot
+    # table would keep a second ~n*k*p buffer alive as a program output
+    return carry[4:7], outs
 
 
 def run_mp_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
@@ -421,8 +427,8 @@ def run_mp_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
     the trajectory — exactly as without the argument.
 
     ``backend`` opts in to the fused ``round_step`` round body
-    (kernels/round_fuse.py; auto keeps fused XLA on CPU/GPU and the Pallas
-    megakernel on TPU).  The fused path carries a flat id-column slot
+    (kernels/round_fuse.py; auto resolves the fused XLA form on every
+    platform).  The fused path carries a flat id-column slot
     table, telescopes the Eq. 6 update from scattered slot deltas, and
     software-pipelines the next round's event draw + operand gathers
     behind the current round's scatters — the same RNG stream and event
@@ -445,25 +451,11 @@ def run_mp_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
     keys = jax.random.split(key, n_rec * record_every).reshape(
         n_rec, record_every, 2)
     ts = jnp.asarray((np.arange(n_rec) * record_every).astype(np.int32))
-    if backend is not None:
-        # fused round body: the carry (warm start, flat id-column slot
-        # table, round 0's prefetch, per-row first-receipt flags) is built
-        # INSIDE the jitted scan from theta_sol — see _scenario_scan — so
-        # the ~n*k*p slot table is neither materialized eagerly nor copied
-        # in as an argument buffer (tens of ms per call at n=10k)
-        carry0 = None
-    else:
-        theta0, K0 = _mp_warm_start(tabs, theta_sol)
-        carry0 = (theta0, K0, theta0, jnp.ones((n,), bool),
-                  jnp.int32(0), jnp.int32(0), jnp.int32(0))
-        if tel:
-            carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
-                               jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    carry, outs = _scenario_scan(
-        tabs, part_half, rates, theta_sol, c, carry0, keys, ts,
+    counters, outs = _scenario_scan(
+        tabs, part_half, rates, theta_sol, c, keys, ts,
         conditions=conditions, alpha=alpha, batch=batch,
         record_every=record_every, n_rec=n_rec, tel=tel, backend=backend)
-    theta, K, _, active, delivered, dropped, invalid = carry[:7]
+    delivered, dropped, invalid = counters
     total_rounds = n_rec * record_every
     frames = None
     if tel:
@@ -506,17 +498,25 @@ class SparseADMMState:
 
 def init_sparse_admm(topo: SparseTopology, theta_sol) -> SparseADMMState:
     """Warm start (paper §4.2): share solitary models with neighbors."""
-    tabs = topo.device_tables()
-    n = topo.n
-    theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(n, -1)
-    p = theta_sol.shape[1]
-    K = theta_sol[tabs.nbr_idx]                               # copies of nbrs
-    Z_own = jnp.broadcast_to(theta_sol[:, None, :],
-                             (n, topo.k_max, p)).astype(jnp.float32)
-    Z_nbr = K.astype(jnp.float32)
-    zeros = jnp.zeros((n, topo.k_max, p), jnp.float32)
-    return SparseADMMState(theta_sol, K.astype(jnp.float32),
-                           Z_own, Z_nbr, zeros, zeros)
+    theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(topo.n, -1)
+    return _warm_admm_state(topo.device_tables().nbr_idx, theta_sol)
+
+
+def _warm_admm_state(nbr_idx, theta_sol) -> SparseADMMState:
+    """:func:`init_sparse_admm` on device arrays (traceable)."""
+    return warm_admm_from_copies(theta_sol, theta_sol[nbr_idx])
+
+
+def warm_admm_from_copies(theta, K) -> SparseADMMState:
+    """The warm-start rule, from the models ``theta`` (n, p) and each
+    agent's copies ``K`` (n, k, p) of its neighbours' models: Z_own holds
+    the own model in every slot, Z_nbr the neighbour copies, both duals
+    start at zero (traceable; the sharded engine applies it per shard)."""
+    n, k, p = K.shape
+    K = K.astype(jnp.float32)
+    Z_own = jnp.broadcast_to(theta[:, None, :], (n, k, p)).astype(jnp.float32)
+    zeros = jnp.zeros((n, k, p), jnp.float32)
+    return SparseADMMState(theta, K, Z_own, K, zeros, zeros)
 
 
 def _sparse_primal_quadratic(st: SparseADMMState, l, nbr_w, deg_count, D,
@@ -669,8 +669,15 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
     the scan ran before primal solvers were pluggable.  A data-hungry
     solver (``primal.needs_data``) additionally receives the rows' padded
     local datasets via ``xym = (x, y, mask)``.
+
+    ``state0`` is a :class:`SparseADMMState`, or ``(nbr_idx, theta_sol)``
+    to build the :func:`init_sparse_admm` warm start here, in-jit: the
+    (n, k, p) state is then a temporary the scan updates in place, never
+    an argument buffer it would have to copy.
     """
     n, k = nbr_w.shape
+    if not isinstance(state0, SparseADMMState):
+        state0 = _warm_admm_state(*state0)
     edge_fn = resolve("cl_edge_step", backend)
 
     def round_fn(carry, ev_t):
@@ -701,9 +708,9 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
         pub = (theta, K, st.L_own, st.L_nbr)
 
         # --- edge phase: one half-step per delivered direction, as one
-        # fused op (kernels/round_fuse.cl_edge_step — CPU/GPU resolve the
+        # fused op (kernels/round_fuse.cl_edge_step — auto resolves the
         # expression-identical XLA form, so the trajectory is bit-for-bit
-        # the inline code's; TPU gets the Pallas megakernel)
+        # the inline code's)
         own_s = jnp.concatenate([ev_t.s, ev_t.r])
         oth_a = jnp.concatenate([ev_t.j, ev_t.i])
         oth_s = jnp.concatenate([ev_t.r, ev_t.s])
@@ -783,7 +790,9 @@ def run_cl_scenario(topo: SparseTopology, data: AgentData, mu: float,
     if state is None:
         if theta_sol is None:
             raise ValueError("need theta_sol (warm start) or explicit state")
-        state = init_sparse_admm(topo, theta_sol)
+        # warm start built inside the jitted scan (see _cl_scenario_scan)
+        state = (tabs.nbr_idx,
+                 jnp.asarray(theta_sol, jnp.float32).reshape(n, -1))
     if stream is None:
         stream = precompute_event_stream(
             tabs, jnp.asarray(topo.partition_halves()), conditions, batch,

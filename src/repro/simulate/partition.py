@@ -54,14 +54,14 @@ from repro.core.graph_learning import prune_rows, reweight_rows
 from repro.core.sparse import (admm_edge_halfstep, batched_admm_primal,
                                batched_model_update, live_slots,
                                record_chunks)
-from repro.launch.sim_mesh import (AGENT_AXIS, HaloCodec, halo_exchange_fn,
-                                   halo_payload_bytes, make_sim_mesh,
-                                   mesh_shards, resolve_halo_codec,
-                                   shard_map_1d)
+from repro.launch.sim_mesh import (AGENT_AXIS, HaloCodec, agent_sharding,
+                                   halo_exchange_fn, halo_payload_bytes,
+                                   make_sim_mesh, mesh_shards,
+                                   resolve_halo_codec)
 from repro.telemetry import metrics as tmetrics
 from repro.telemetry.config import TelemetryConfig, telemetry_on
 from repro.telemetry.frames import TelemetryFrames
-from .engines import (SimTrace, _reshape_stream, init_sparse_admm)
+from .engines import SimTrace, _reshape_stream, warm_admm_from_copies
 from .scheduler import (EventStream, NetworkConditions,
                         precompute_event_stream, stream_totals)
 from .topology import SparseTopology
@@ -366,6 +366,21 @@ def _sharded_setup(topo, n_shards, mesh, assignment, partition_seed):
     return mesh, P_, assignment, part
 
 
+def _on_shards(mesh, x):
+    """Place host rows in the stacked (P * m, ...) layout (or one row per
+    shard) on the mesh, each device receiving only its own block — never
+    the whole array on one device first."""
+    return jax.device_put(np.asarray(x), agent_sharding(mesh))
+
+
+def _partition_args(mesh, part: GraphPartition) -> dict:
+    """The per-shard halo tables every sharded scan takes, on the mesh."""
+    return dict(fetch=_on_shards(mesh, part.fetch),
+                bnd_pos=_on_shards(mesh, part.bnd_pos),
+                halo_src_shard=_on_shards(mesh, part.halo_src_shard),
+                halo_src_pos=_on_shards(mesh, part.halo_src_pos))
+
+
 def _local_capacities(batch: int, P_: int, local_batch) -> tuple:
     """Per-shard static (event, update) capacities ``(E, U)`` — the
     8-sigma defaults, or the lossless explicit-capacity override."""
@@ -526,8 +541,8 @@ def _sharded_scenario_scan(mesh, stream, theta0, K0, nbr_p, c, sol,
     if tel:
         out_specs = out_specs + (P(None, AGENT_AXIS), P(None, AGENT_AXIS),
                                  P(None, AGENT_AXIS))
-    run = shard_map_1d(
-        block_fn, mesh,
+    run = jax.shard_map(
+        block_fn, mesh=mesh, check_vma=False,
         in_specs=(_scan_specs(P(), ev_scan), P(AGENT_AXIS), P(AGENT_AXIS),
                   P(AGENT_AXIS), P(AGENT_AXIS), P(AGENT_AXIS),
                   P(AGENT_AXIS, None), P(AGENT_AXIS, None),
@@ -585,10 +600,8 @@ def run_mp_scenario_sharded(topo: SparseTopology, theta_sol, c, alpha: float,
     tel = telemetry_on(telemetry)
     codec = resolve_halo_codec(halo_codec)
     outs = _sharded_scenario_scan(
-        mesh, stream, **{k: jnp.asarray(v) for k, v in sharded.items()},
-        fetch=jnp.asarray(part.fetch), bnd_pos=jnp.asarray(part.bnd_pos),
-        halo_src_shard=jnp.asarray(part.halo_src_shard),
-        halo_src_pos=jnp.asarray(part.halo_src_pos),
+        mesh, stream, **{k: _on_shards(mesh, v) for k, v in sharded.items()},
+        **_partition_args(mesh, part),
         alpha=alpha, m=part.shard_size, H=part.halo_size,
         E=E, U=U, n_rec=n_rec, record_every=record_every,
         exchange=exchange, codec=codec, tel=tel)
@@ -623,9 +636,9 @@ def run_mp_scenario_sharded(topo: SparseTopology, theta_sol, c, alpha: float,
          static_argnames=("mesh", "mu", "rho", "k", "m", "H", "E", "U",
                           "n_rec", "record_every", "exchange", "codec",
                           "tel", "primal"))
-def _sharded_cl_scan(mesh, stream, theta0, K0, Zo0, Zn0, Lo0, Ln0,
-                     nbr_w, deg_count, D, m_counts, sx,
-                     fetch, bnd_pos, halo_src_shard, halo_src_pos,
+def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
+                     m_counts, sx, fetch, bnd_pos, halo_src_shard,
+                     halo_src_pos,
                      tel_args=(), xym=(), *,
                      mu: float, rho: float, k: int, m: int, H: int, E: int,
                      U: int, n_rec: int, record_every: int, exchange: str,
@@ -633,7 +646,9 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, Zo0, Zn0, Lo0, Ln0,
                      tel: bool = False, primal=None):
     """shard_map'd CL-ADMM rounds: the six ADMM state arrays are row-sharded
     (P * m leading axis); the event stream is replicated and replayed per
-    shard exactly as the MP engine does.
+    shard exactly as the MP engine does.  Only the warm-start models and
+    neighbour copies come in as arguments; each shard builds the rest of
+    its state in-jit (``engines.warm_admm_from_copies``).
 
     Edge state never leaves its owner: for a cross-shard edge each endpoint
     shard keeps its own (Z_own, Z_nbr, L_own, L_nbr) slots and mirrors the
@@ -651,9 +666,9 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, Zo0, Zn0, Lo0, Ln0,
     batch = stream.i.shape[-1]
     n_xym = 3 if (primal is not None and primal.needs_data) else 0
 
-    def block_fn(ev, theta0_blk, K0_blk, Zo_blk, Zn_blk, Lo_blk, Ln_blk,
-                 w_blk, degc_blk, D_blk, mc_blk, sx_blk,
-                 fetch_blk, bnd_blk, hsrc_blk, hpos_blk, *extra_blks):
+    def block_fn(ev, theta0_blk, K0_blk, w_blk, degc_blk, D_blk, mc_blk,
+                 sx_blk, fetch_blk, bnd_blk, hsrc_blk, hpos_blk,
+                 *extra_blks):
         xym_blk = extra_blks[:n_xym]
         tel_blks = extra_blks[n_xym:]
         fetch_q = fetch_blk[0]
@@ -763,9 +778,10 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, Zo0, Zn0, Lo0, Ln0,
                 return carry, (carry[0], obj, stale, updates[None])
             return carry, carry[0]
 
-        ext0 = publish(theta0_blk, K0_blk, Lo_blk, Ln_blk)  # warm-start halo
-        carry0 = (theta0_blk, K0_blk, Zo_blk, Zn_blk, Lo_blk, Ln_blk, ext0,
-                  jnp.int32(0))
+        st0 = warm_admm_from_copies(theta0_blk, K0_blk)
+        ext0 = publish(st0.theta, st0.K, st0.L_own, st0.L_nbr)  # warm halo
+        carry0 = (st0.theta, st0.K, st0.Z_own, st0.Z_nbr, st0.L_own,
+                  st0.L_nbr, ext0, jnp.int32(0))
         if tel:
             carry0 = carry0 + (jnp.zeros((m,), jnp.int32), jnp.int32(0))
         carry, hist = jax.lax.scan(outer, carry0, ev)
@@ -781,12 +797,12 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, Zo0, Zn0, Lo0, Ln0,
     out_specs = (P(None, AGENT_AXIS, None), row, row)
     if tel:
         out_specs = out_specs + (P(None, AGENT_AXIS),) * 3
-    run = shard_map_1d(
-        block_fn, mesh,
-        in_specs=(_scan_specs(P(), ev_scan),) + (row,) * 11
+    run = jax.shard_map(
+        block_fn, mesh=mesh, check_vma=False,
+        in_specs=(_scan_specs(P(), ev_scan),) + (row,) * 7
         + (per_shard,) * 4 + (row,) * n_xym + (row,) * len(tel_args),
         out_specs=out_specs)
-    return run(ev_scan, theta0, K0, Zo0, Zn0, Lo0, Ln0, nbr_w, deg_count,
+    return run(ev_scan, theta0, K0, nbr_w, deg_count,
                D, m_counts, sx, fetch, bnd_pos, halo_src_shard,
                halo_src_pos, *xym, *tel_args)
 
@@ -845,7 +861,9 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
 
     if theta_sol is None:
         raise ValueError("need theta_sol (warm start)")
-    state0 = init_sparse_admm(topo, theta_sol)
+    # warm start (paper §4.2): the neighbour copies are gathered on the
+    # host (they cross shards); each shard derives the rest in-jit
+    theta0 = np.asarray(theta_sol, np.float32).reshape(topo.n, -1)
     # the local-data reductions use the same jnp expressions as the
     # single-device engine (numpy's pairwise summation rounds differently,
     # which would break the bit-for-bit parity)
@@ -856,15 +874,11 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
     needs_data = primal is not None and primal.needs_data
     xym = ()
     if needs_data:
-        xym = tuple(jnp.asarray(part.shard_rows(np.asarray(a)))
+        xym = tuple(_on_shards(mesh, part.shard_rows(np.asarray(a)))
                     for a in (x, jnp.asarray(data.y, jnp.float32), mask))
     sharded = dict(
-        theta0=part.shard_rows(np.asarray(state0.theta)),
-        K0=part.shard_rows(np.asarray(state0.K)),
-        Zo0=part.shard_rows(np.asarray(state0.Z_own)),
-        Zn0=part.shard_rows(np.asarray(state0.Z_nbr)),
-        Lo0=part.shard_rows(np.asarray(state0.L_own)),
-        Ln0=part.shard_rows(np.asarray(state0.L_nbr)),
+        theta0=part.shard_rows(theta0),
+        K0=part.shard_rows(theta0[tabs.nbr_idx]),
         nbr_w=part.shard_rows(tabs.nbr_w),
         deg_count=part.shard_rows(tabs.deg_count),
         D=part.shard_rows(tabs.deg_w.astype(np.float32)),
@@ -876,13 +890,12 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
     tel_args = ()
     if tel and not needs_data:
         sxx = np.asarray(jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1))
-        tel_args = (jnp.asarray(part.shard_rows(sxx)),)
+        tel_args = (_on_shards(mesh, part.shard_rows(sxx)),)
     codec = resolve_halo_codec(halo_codec)
     outs = _sharded_cl_scan(
-        mesh, stream, **{k_: jnp.asarray(v) for k_, v in sharded.items()},
-        fetch=jnp.asarray(part.fetch), bnd_pos=jnp.asarray(part.bnd_pos),
-        halo_src_shard=jnp.asarray(part.halo_src_shard),
-        halo_src_pos=jnp.asarray(part.halo_src_pos), tel_args=tel_args,
+        mesh, stream,
+        **{k_: _on_shards(mesh, v) for k_, v in sharded.items()},
+        **_partition_args(mesh, part), tel_args=tel_args,
         xym=xym,
         mu=mu, rho=rho, k=topo.k_max, m=part.shard_size, H=part.halo_size,
         E=E, U=U, n_rec=n_rec, record_every=record_every,
@@ -890,7 +903,7 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
     frames = None
     if tel:
         hist, theta, overflow, obj_h, stale_h, upd_h = outs
-        p_dim = int(np.asarray(state0.theta).shape[1])
+        p_dim = int(theta0.shape[1])
         frames = _sharded_frames(
             part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
             overflow,
@@ -1098,8 +1111,8 @@ def _sharded_joint_scan(mesh, stream, ts, theta0, K0, theta_prev0, w0,
         + (row,) * 5 + (P(AGENT_AXIS),) * 2
     if tel:
         out_specs = out_specs + (P(None, AGENT_AXIS),) * 4 + (row,)
-    run = shard_map_1d(
-        block_fn, mesh,
+    run = jax.shard_map(
+        block_fn, mesh=mesh, check_vma=False,
         in_specs=(_scan_specs(P(), ev_scan), P()) + (row,) * 7
         + (per_shard,) * 4 + (row,) * len(tel_args),
         out_specs=out_specs)
@@ -1171,13 +1184,13 @@ def run_joint_scenario_sharded(topo: SparseTopology, theta_sol, c,
 
     K0 = theta_sol[tabs.nbr_idx]                     # warm start (§3.2)
     live0 = np.asarray(live_slots(tabs.deg_count, tabs.k_max))
-    theta = jnp.asarray(part.shard_rows(theta_sol))
-    K = jnp.asarray(part.shard_rows(K0))
+    theta = _on_shards(mesh, part.shard_rows(theta_sol))
+    K = _on_shards(mesh, part.shard_rows(K0))
     theta_prev = theta
-    w = jnp.asarray(part.shard_rows(tabs.nbr_p))
-    live = jnp.asarray(part.shard_rows(live0))
-    c_sh = jnp.asarray(part.shard_rows(c))
-    sol_sh = jnp.asarray(part.shard_rows(theta_sol))
+    w = _on_shards(mesh, part.shard_rows(tabs.nbr_p))
+    live = _on_shards(mesh, part.shard_rows(live0))
+    c_sh = _on_shards(mesh, part.shard_rows(c))
+    sol_sh = _on_shards(mesh, part.shard_rows(theta_sol))
     E, U = _local_capacities(batch, P_, local_batch)
 
     # segment schedule (record chunks per jitted call)
@@ -1212,10 +1225,7 @@ def run_joint_scenario_sharded(topo: SparseTopology, theta_sol, c,
         (hist, live_hist, theta, K, theta_prev, w, live, sup, ovf,
          *tel_out) = _sharded_joint_scan(
                 mesh, ev_seg, ts_seg, theta, K, theta_prev, w, live,
-                c_sh, sol_sh, jnp.asarray(part.fetch),
-                jnp.asarray(part.bnd_pos),
-                jnp.asarray(part.halo_src_shard),
-                jnp.asarray(part.halo_src_pos),
+                c_sh, sol_sh, *_partition_args(mesh, part).values(),
                 (stale,) if tel else (), alpha=alpha,
                 eta_graph=eta_graph, lam=lam, graph_every=graph_every,
                 prune_eps=prune_eps, m=part.shard_size, H=part.halo_size,

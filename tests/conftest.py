@@ -1,4 +1,5 @@
-"""Shared test configuration: deterministic hypothesis profiles.
+"""Shared test configuration: deterministic hypothesis profiles, and
+compiled programs released after each test module.
 
 Hypothesis is an optional dev dependency (requirements-dev.txt): the suite
 must import cleanly without it (property tests guard with
@@ -17,6 +18,9 @@ Profiles:
 
 import os
 
+import jax
+import pytest
+
 try:
     from hypothesis import HealthCheck, settings
 
@@ -27,3 +31,17 @@ try:
     settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "dev"))
 except ImportError:  # pragma: no cover - exercised only without dev deps
     pass
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop JAX's compiled programs when a test module ends.
+
+    Every XLA:CPU executable holds a few memory mappings of its own, and
+    the suite compiles thousands of programs in one process: kept until
+    exit they pass the kernel's per-process limit (``vm.max_map_count``,
+    65530 by default) and the next compile dies with SIGSEGV.  Programs
+    are still shared by the tests of one module.
+    """
+    yield
+    jax.clear_caches()

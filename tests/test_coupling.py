@@ -67,7 +67,10 @@ class TestMPCoupling:
         star = np.asarray(closed_form(g, np.asarray(sol["t"]), conf, alpha))
         np.testing.assert_allclose(np.asarray(theta["t"]), star, atol=1e-4)
 
-    def test_kernel_path_matches_einsum_path(self):
+    def test_kernel_path_matches_einsum_path(self, monkeypatch):
+        # use_kernel requests the Pallas impl; off-TPU it runs only with
+        # the explicit interpret opt-in
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
         A = 8
         g = ring_graph(A)
         state = make_state(g, np.ones(A) * 0.5, 0.9)

@@ -209,19 +209,24 @@ class TestSelectionRules:
                                    atol=1e-5, rtol=1e-5)
 
     def test_auto_never_picks_interpret_impl_any_platform(self, monkeypatch):
-        """The satellite rule, pinned for both platforms: auto resolution
-        must never return an impl that would run in Pallas interpret mode —
-        off-TPU it falls back to XLA even under the env opt-in, and on TPU
-        it skips interpret-only registrations (admm_edge's Pallas kernel)."""
+        """The auto rule, pinned for every platform: auto resolution never
+        returns a Pallas impl — not in interpret mode (even under the env
+        opt-in) and not compiled on TPU either, where it resolves the same
+        fused XLA impl as on CPU (no chip measurement justifies a Pallas
+        default)."""
         monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
         for platform in ("cpu", "gpu", "tpu"):
             monkeypatch.setattr(dispatch, "_platform", lambda p=platform: p)
             for op in dispatch.ops():
                 name = dispatch._auto_impl(op)
                 entry = dispatch._REGISTRY[op][name]
+                assert not entry.pallas, (platform, op, name)
                 assert not entry.interpret_only, (platform, op, name)
-                if platform != "tpu":
-                    assert not entry.pallas, (platform, op, name)
+                want = ("xla" if "xla" in dispatch.implementations(op)
+                        else "reference")
+                assert name == want, (platform, op, name)
+                assert resolve(op, ReproBackend()) \
+                    is dispatch._REGISTRY[op][want].make(False)
 
     def test_interpret_only_impl_needs_opt_in_everywhere(self, monkeypatch):
         """admm_edge/pallas is interpret-only: unavailable and unresolvable

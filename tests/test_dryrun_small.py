@@ -19,31 +19,30 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.configs import get_config
     from repro.launch import dryrun as dr
-    from repro.launch.mesh import use_mesh
-    from repro.launch.hlo_analysis import cost_dict
     from repro.launch.shapes import InputShape
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     out = {}
     for arch in ("llama3_8b", "olmoe_1b_7b", "xlstm_1_3b",
                  "recurrentgemma_2b", "qwen2_vl_7b", "musicgen_medium"):
         cfg = get_config(arch, "reduced")
         shape = InputShape("t", 64 if cfg.family != "vlm" else 64, 8, "train")
         jitted, args, model = dr.build_train(cfg, shape, mesh, "dense", "mp")
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jitted.lower(*args).compile()
-        c = cost_dict(compiled)
+        c = compiled.cost_analysis()
         out[arch + "/train"] = float(c.get("flops", 0))
         dshape = InputShape("d", 64, 8, "decode")
         jitted, args, model = dr.build_decode(cfg, dshape, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jitted.lower(*args).compile()
-        out[arch + "/decode"] = float(cost_dict(compiled).get("flops", 0))
+        out[arch + "/decode"] = float(compiled.cost_analysis().get("flops", 0))
     # gossip schedule lowers too
     cfg = get_config("llama3_8b", "reduced")
     shape = InputShape("t", 64, 8, "train")
     jitted, args, model = dr.build_train(cfg, shape, mesh, "gossip", "mp")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
     stats = __import__("repro.launch.hlo_analysis",
                        fromlist=["collective_stats"]).collective_stats(

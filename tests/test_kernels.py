@@ -32,7 +32,7 @@ def test_graph_mix_matches_ref(n, D, dtype):
     sol = jax.random.normal(k2, (n, D), dtype)
     A = jax.random.uniform(k3, (n, n), jnp.float32) / n
     b = jax.random.uniform(k4, (n,), jnp.float32)
-    got = ops.graph_mix(theta, sol, A, b)
+    got = ops.graph_mix(theta, sol, A, b, interpret=True)
     want = ref.graph_mix(theta, sol, A, b)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
@@ -52,7 +52,7 @@ def test_graph_mix_is_mp_step():
     A = (alpha / denom)[:, None] * np.asarray(g.P, np.float32)
     b = abar * c / denom
     one_step = ops.graph_mix(jnp.asarray(theta_sol), jnp.asarray(theta_sol),
-                             jnp.asarray(A), jnp.asarray(b))
+                             jnp.asarray(A), jnp.asarray(b), interpret=True)
     want = synchronous(g, theta_sol, c, alpha, steps=1)
     np.testing.assert_allclose(np.asarray(one_step), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
@@ -67,7 +67,7 @@ def test_graph_mix_property_random_shapes(n, D):
     sol = jax.random.normal(k2, (n, D))
     A = jax.random.uniform(k3, (n, n)) / n
     b = jax.random.uniform(k4, (n,))
-    got = ops.graph_mix(theta, sol, A, b)
+    got = ops.graph_mix(theta, sol, A, b, interpret=True)
     want = ref.graph_mix(theta, sol, A, b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4,
                                rtol=1e-4)
@@ -89,7 +89,7 @@ def test_flash_attention_matches_ref(S, block, window, dtype):
     k = jax.random.normal(k2, (B, S, H, hd), dtype)
     v = jax.random.normal(k3, (B, S, H, hd), dtype)
     got = ops.flash_attention(q, k, v, window=window, block_q=block,
-                              block_k=block)
+                              block_k=block, interpret=True)
     want = ref.flash_attention(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
@@ -102,7 +102,8 @@ def test_flash_attention_gqa_expansion():
     q = jax.random.normal(k1, (B, S, H, hd))
     k = jax.random.normal(k2, (B, S, K, hd))
     v = jax.random.normal(k3, (B, S, K, hd))
-    got = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    got = ops.flash_attention(q, k, v, block_q=64, block_k=64,
+                              interpret=True)
     kf = jnp.repeat(k, H // K, axis=2)
     vf = jnp.repeat(v, H // K, axis=2)
     want = ref.flash_attention(q, kf, vf)
@@ -119,7 +120,8 @@ def test_flash_attention_matches_model_chunked_path():
     q = jax.random.normal(k1, (B, S, H, hd))
     k = jax.random.normal(k2, (B, S, H, hd))
     v = jax.random.normal(k3, (B, S, H, hd))
-    got = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    got = ops.flash_attention(q, k, v, block_q=64, block_k=64,
+                              interpret=True)
     want = chunked_attention(q, k, v, chunk=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4,
                                rtol=1e-4)
@@ -138,7 +140,7 @@ def test_flash_attention_property(sblk, window, hd):
     k = jax.random.normal(k2, (B, S, H, hd))
     v = jax.random.normal(k3, (B, S, H, hd))
     got = ops.flash_attention(q, k, v, window=window, block_q=block,
-                              block_k=block)
+                              block_k=block, interpret=True)
     want = ref.flash_attention(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4,
                                rtol=1e-4)
@@ -156,7 +158,7 @@ def test_admm_update_matches_ref(E, p, dtype):
     args = [jax.random.normal(k, (E, p), dtype)
             for k in jax.random.split(key, 8)]
     rho = 1.5
-    got = ops.admm_edge_update(*args, rho=rho)
+    got = ops.admm_edge_update(*args, rho=rho, interpret=True)
     want = ref.admm_edge_update(*args, rho=rho)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g, np.float32),
@@ -193,7 +195,7 @@ def test_admm_update_matches_core_algorithm():
         jnp.asarray(np.asarray(st0.L_nbr)[ii, jj]),
         jnp.asarray(np.asarray(st0.L_own)[jj, ii]),
         jnp.asarray(np.asarray(st0.L_nbr)[jj, ii]),
-        rho=rho)
+        rho=rho, interpret=True)
     np.testing.assert_allclose(np.asarray(z_i),
                                np.asarray(st1.Z_own)[ii, jj], atol=1e-5)
     np.testing.assert_allclose(np.asarray(z_j),

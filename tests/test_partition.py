@@ -232,6 +232,16 @@ class TestShardedParity:
             run_mp_scenario_sharded(topo, sol, c, 0.9, CONDITIONS["clean"],
                                     rounds=10, batch=8, assignment=bad)
 
+    def test_mesh_larger_than_device_count_raises(self):
+        """Asking for more shards than devices is an error, never a
+        silently smaller mesh."""
+        from repro.launch.sim_mesh import make_sim_mesh, mesh_shards
+        with pytest.raises(ValueError, match="device"):
+            make_sim_mesh(jax.device_count() + 1)
+        with pytest.raises(ValueError, match="device"):
+            make_sim_mesh(0)
+        assert mesh_shards(make_sim_mesh()) == jax.device_count()
+
     def test_replay_parity_churn_and_partition_together(self, problem):
         """EventStream replay with churn AND a partition window active at
         once: the materialized stream and the inline engine agree on every

@@ -529,7 +529,7 @@ def test_sparse_gather_mix_matches_ref(n, k, p, dtype):
     b = jnp.asarray(rng.uniform(0, 1, n), jnp.float32)
     table = jnp.asarray(rng.standard_normal((n, p)), dtype)
     sol = jnp.asarray(rng.standard_normal((n, p)), dtype)
-    got = ops.sparse_gather_mix(table, idx, w, b, sol)
+    got = ops.sparse_gather_mix(table, idx, w, b, sol, interpret=True)
     want = ref.sparse_gather_mix(table, idx, w, b, sol)
     tol = dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 else \
         dict(atol=1e-5, rtol=1e-5)
@@ -537,7 +537,10 @@ def test_sparse_gather_mix_matches_ref(n, k, p, dtype):
                                np.asarray(want, np.float32), **tol)
 
 
-def test_sync_sweep_kernel_path_matches_jnp_path():
+def test_sync_sweep_kernel_path_matches_jnp_path(monkeypatch):
+    # use_kernel requests the Pallas impl; off-TPU it runs only with the
+    # explicit interpret opt-in
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     topo = random_geometric_topology(200, k=5, seed=1)
     rng = np.random.default_rng(4)
     sol = rng.standard_normal((200, 8)).astype(np.float32)

@@ -8,8 +8,8 @@ the worst (2-D meshes are on the roadmap).  The rule accepts a collective
 when either
 
 * it sits lexically inside a function that this module passes to a
-  ``shard_map``-family call (``jax.shard_map``, ``shard_map_1d``,
-  ``_shard_map``, ...) — the binding is visible locally; or
+  ``shard_map``-family call (``jax.shard_map``, ...) — the binding is
+  visible locally; or
 * an enclosing function's docstring mentions ``shard_map`` — the
   documented caller-binds contract (e.g. ``halo_exchange_fn``'s closures,
   ``gossip_mix_tree``), which keeps the obligation readable at the def.
