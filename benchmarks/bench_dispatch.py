@@ -362,35 +362,23 @@ def main(argv=None) -> int:
     ap.add_argument("--baseline", default=None,
                     help="committed baseline JSON to gate against (fail on "
                          "parity drift or >2x normalized slowdown)")
-    ap.add_argument("--profile", default=None,
-                    help="wrap the op sweep in jax.profiler.trace writing "
-                         "to this directory (kernels are attributable via "
-                         "the repro/<op>/<impl> named scopes)")
     args = ap.parse_args(argv)
     # gating needs stable medians; plain smoke stays cheap
     repeats = args.repeats or (5 if args.baseline or not args.smoke else 1)
     interpret = args.smoke or args.interpret
 
-    def sweep():
-        return {
-            "mix": bench_mix(args.smoke, interpret, repeats),
-            "sparse_mix": bench_sparse_mix(args.smoke, interpret, repeats),
-            "admm_primal": bench_admm_primal(args.smoke, interpret, repeats),
-            "admm_edge": bench_admm_edge(args.smoke, interpret, repeats),
-            "edge_reweight": bench_edge_reweight(args.smoke, interpret,
-                                                 repeats),
-            # the fused gossip round across engine-realistic batch sizes
-            # (n // 10 wake-ups per round at n = 640 / 5k / 40k)
-            **{f"round_step_b{B}": bench_round_step(args.smoke, interpret,
-                                                    repeats, B)
-               for B in (64, 512, 4096)},
-        }
-
-    if args.profile:
-        with jax.profiler.trace(args.profile):
-            ops = sweep()
-    else:
-        ops = sweep()
+    ops = {
+        "mix": bench_mix(args.smoke, interpret, repeats),
+        "sparse_mix": bench_sparse_mix(args.smoke, interpret, repeats),
+        "admm_primal": bench_admm_primal(args.smoke, interpret, repeats),
+        "admm_edge": bench_admm_edge(args.smoke, interpret, repeats),
+        "edge_reweight": bench_edge_reweight(args.smoke, interpret, repeats),
+        # the fused gossip round across engine-realistic batch sizes
+        # (n // 10 wake-ups per round at n = 640 / 5k / 40k)
+        **{f"round_step_b{B}": bench_round_step(args.smoke, interpret,
+                                                repeats, B)
+           for B in (64, 512, 4096)},
+    }
     report = {
         "meta": {
             "platform": jax.default_backend(),

@@ -37,10 +37,7 @@ invocation shape matches the baseline's.  Refresh the committed baseline
 with the CI invocation plus ``--out BENCH_network_sim.baseline.json``.
 
 ``--run-dir DIR`` records each telemetry-enabled run as a run directory
-(manifest.json + metrics.jsonl, rendered by ``tools/trace_report.py``);
-``--profile DIR`` wraps one timed single-device run per (scenario, n) in
-``jax.profiler.trace`` so the ``repro/<op>/<impl>`` named scopes from
-``kernels.dispatch`` show up attributed in TensorBoard/Perfetto.
+(manifest.json + metrics.jsonl, rendered by ``tools/trace_report.py``).
 """
 
 from __future__ import annotations
@@ -146,7 +143,7 @@ def _sharded_runner(algo: str, topo, p: int, seed: int):
 
 def bench_one(n: int, k: int, p: int, scenario_name: str, rounds: int,
               batch: int, seed: int = 0, algo: str = "mp", repeats: int = 1,
-              telemetry=None, profile_dir=None, backend=None):
+              telemetry=None, backend=None):
     """Timed single-device run; returns (report row, trace).
 
     ``backend`` (mp only) routes the round body through a fused
@@ -169,9 +166,6 @@ def bench_one(n: int, k: int, p: int, scenario_name: str, rounds: int,
     if backend is not None:
         kw["backend"] = backend
     tr = run(cond, **kw)
-    if profile_dir is not None:
-        with jax.profiler.trace(profile_dir):
-            run(cond, **kw)
     dt = time_call(run, cond, repeats=repeats, warmup=0, **kw) / 1e6
 
     # the ADMM state carries 5 extra (n, k, p) arrays beyond MP's one; the
@@ -321,9 +315,6 @@ def main(argv=None) -> int:
     ap.add_argument("--run-dir", default=None,
                     help="record telemetry-enabled runs as run directories "
                          "(manifest.json + metrics.jsonl) under this path")
-    ap.add_argument("--profile", default=None,
-                    help="wrap one timed run per config in "
-                         "jax.profiler.trace writing to this directory")
     args = ap.parse_args(argv)
 
     ns = [int(x) for x in args.ns.split(",") if x]
@@ -343,8 +334,7 @@ def main(argv=None) -> int:
         batch = args.batch or max(1, n // 10)
         for name in names:
             r, _ = bench_one(n, args.k, args.p, name, args.rounds, batch,
-                             algo=args.algo, repeats=args.repeats,
-                             profile_dir=args.profile)
+                             algo=args.algo, repeats=args.repeats)
             r["name"] = f"network_sim/{args.algo}/{name}/n{n}"
             worst_rss = max(worst_rss, r["peak_rss_mb"])
             emit(r["name"], r["time_s"] * 1e6,

@@ -46,6 +46,7 @@ from repro.telemetry import metrics as tmetrics
 from repro.telemetry.config import TelemetryConfig, telemetry_on
 from repro.telemetry.frames import TelemetryFrames
 from . import scheduler as sched
+from . import spans
 from .scheduler import (EventStream, NetworkConditions,
                         precompute_event_stream, stream_totals)
 from .topology import SparseTopology
@@ -255,38 +256,39 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
     n = theta_sol.shape[0]
     fused = backend is not None
     step_fn = resolve("round_step", backend) if fused else None
-    theta0, K0 = _mp_warm_start(tabs, theta_sol)
-    if fused:
-        km = tabs.nbr_idx.shape[1]
-        no_stale = conditions.stale_prob == 0.0
-        a_w = round_scales(tabs.nbr_p, c, alpha=alpha)
-        theta_base = batched_model_update(tabs.nbr_p, K0, c, theta_sol,
-                                          alpha)
-        # build the fused carry (slot table, round 0's prefetch from the
-        # unshifted first key, first-receipt flags); the scan then consumes
-        # the keys shifted one round ahead (the last key's second draw is
-        # discarded), so the carried prefetch sees the bitwise-identical
-        # RNG stream
-        active0 = jnp.ones((n,), bool)
-        Ke0 = encode_slots(K0)
-        flat = keys.reshape(-1, 2)
-        k_ev, k_churn = jax.random.split(flat[0])
-        ev0 = sched.draw_events(k_ev, conditions, tabs, part_half, active0,
-                                rates, 0, batch)
-        pf0 = (ev0,) + round_prefetch(
-            theta0, theta0, Ke0, ev0.i, ev0.j, ev0.s, ev0.r,
-            ev0.deliver_ij, ev0.deliver_ji, ev0.stale_ij, ev0.stale_ji,
-            no_stale=no_stale) + (k_churn,)
-        keys = jnp.concatenate([flat[1:], flat[-1:]]).reshape(
-            n_rec, record_every, 2)
-        carry0 = (theta0, Ke0, pf0, active0, jnp.int32(0), jnp.int32(0),
-                  jnp.int32(0), jnp.zeros((n,), bool))
-    else:
-        carry0 = (theta0, K0, theta0, jnp.ones((n,), bool),
-                  jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    if tel:
-        carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
-                           jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    km = tabs.nbr_idx.shape[1]
+    no_stale = conditions.stale_prob == 0.0
+    with spans.phase("init"):
+        theta0, K0 = _mp_warm_start(tabs, theta_sol)
+        if fused:
+            a_w = round_scales(tabs.nbr_p, c, alpha=alpha)
+            theta_base = batched_model_update(tabs.nbr_p, K0, c, theta_sol,
+                                              alpha)
+            # build the fused carry (slot table, round 0's prefetch from
+            # the unshifted first key, first-receipt flags); the scan then
+            # consumes the keys shifted one round ahead (the last key's
+            # second draw is discarded), so the carried prefetch sees the
+            # bitwise-identical RNG stream
+            active0 = jnp.ones((n,), bool)
+            Ke0 = encode_slots(K0)
+            flat = keys.reshape(-1, 2)
+            k_ev, k_churn = jax.random.split(flat[0])
+            ev0 = sched.draw_events(k_ev, conditions, tabs, part_half,
+                                    active0, rates, 0, batch)
+            pf0 = (ev0,) + round_prefetch(
+                theta0, theta0, Ke0, ev0.i, ev0.j, ev0.s, ev0.r,
+                ev0.deliver_ij, ev0.deliver_ji, ev0.stale_ij, ev0.stale_ji,
+                no_stale=no_stale) + (k_churn,)
+            keys = jnp.concatenate([flat[1:], flat[-1:]]).reshape(
+                n_rec, record_every, 2)
+            carry0 = (theta0, Ke0, pf0, active0, jnp.int32(0), jnp.int32(0),
+                      jnp.int32(0), jnp.zeros((n,), bool))
+        else:
+            carry0 = (theta0, K0, theta0, jnp.ones((n,), bool),
+                      jnp.int32(0), jnp.int32(0), jnp.int32(0))
+        if tel:
+            carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
+                               jnp.int32(0), jnp.int32(0), jnp.int32(0))
 
     def round_fn(carry, inp):
         t, key = inp
@@ -303,80 +305,95 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
             # scatters theta in place instead of copying the model table
             # every round (~25% of the fused round on CPU at n=10k); the
             # barrier pins the gather-before-scatter order.
-            active2 = sched.churn_step(k_churn, conditions, active)
-            k_ev2, k_churn2 = jax.random.split(key)
-            ev2 = sched.draw_events(k_ev2, conditions, tabs, part_half,
-                                    active2, rates, t + 1, batch)
+            with spans.phase("draw"):
+                active2 = sched.churn_step(k_churn, conditions, active)
+                k_ev2, k_churn2 = jax.random.split(key)
+                ev2 = sched.draw_events(k_ev2, conditions, tabs, part_half,
+                                        active2, rates, t + 1, batch)
             if no_stale:
                 # zero staleness (static): no previous-model reads at all,
                 # so the step is already theta_in's last consumer
                 stale_src = None
             else:
-                stale_src = round_stale_src(theta_in, ev2.i, ev2.j)
-                theta_in, stale_src = jax.lax.optimization_barrier(
-                    (theta_in, stale_src))
-            theta, K, got_ever, _ = step_fn(theta_in, K, got_ever, msg,
-                                            tgt_row, enc, k_old, theta_base,
-                                            a_w)
+                with spans.phase("comm"):
+                    stale_src = round_stale_src(theta_in, ev2.i, ev2.j)
+                    theta_in, stale_src = jax.lax.optimization_barrier(
+                        (theta_in, stale_src))
+            with spans.phase("update"):
+                theta, K, got_ever, _ = step_fn(theta_in, K, got_ever, msg,
+                                                tgt_row, enc, k_old,
+                                                theta_base, a_w)
         else:
             theta, K, theta_prev, active, delivered, dropped, invalid, \
                 *tstate = carry
             theta_in = theta              # next round's "one-round-old" model
-            k_ev, k_churn = jax.random.split(key)
-            ev = sched.draw_events(k_ev, conditions, tabs, part_half, active,
-                                   rates, t, batch)
-        upd = jnp.concatenate([ev.i, ev.j])                      # (2B,)
-        got = jnp.concatenate([ev.deliver_ji, ev.deliver_ij])
-        got &= active[upd]
+            with spans.phase("draw"):
+                k_ev, k_churn = jax.random.split(key)
+                ev = sched.draw_events(k_ev, conditions, tabs, part_half,
+                                       active, rates, t, batch)
+        with spans.phase("update"):
+            upd = jnp.concatenate([ev.i, ev.j])                  # (2B,)
+            got = jnp.concatenate([ev.deliver_ji, ev.deliver_ij])
+            got &= active[upd]
 
         if not fused:
             # --- communication: all scatters land before any update reads
-            msg_i = jnp.where(ev.stale_ij[:, None], theta_prev[ev.i],
-                              theta[ev.i])
-            msg_j = jnp.where(ev.stale_ji[:, None], theta_prev[ev.j],
-                              theta[ev.j])
-            # undelivered messages scatter out of bounds -> dropped by XLA
-            row_j = jnp.where(ev.deliver_ij, ev.j, n)
-            row_i = jnp.where(ev.deliver_ji, ev.i, n)
-            # scatter: last-write-wins — a repeated edge in one batch lands
-            # the batch-order winner; kernels/round_fuse.round_step dedups
-            # to the same winner so both paths agree bit-for-bit
-            K = K.at[row_j, ev.r].set(msg_i, mode="drop")
-            K = K.at[row_i, ev.s].set(msg_j, mode="drop")  # scatter: last-write-wins
+            with spans.phase("comm"):
+                msg_i = jnp.where(ev.stale_ij[:, None], theta_prev[ev.i],
+                                  theta[ev.i])
+                msg_j = jnp.where(ev.stale_ji[:, None], theta_prev[ev.j],
+                                  theta[ev.j])
+                # undelivered messages scatter out of bounds -> dropped
+                row_j = jnp.where(ev.deliver_ij, ev.j, n)
+                row_i = jnp.where(ev.deliver_ji, ev.i, n)
+                # scatter: last-write-wins — a repeated edge in one batch
+                # lands the batch-order winner; kernels/round_fuse.
+                # round_step dedups to the same winner so both paths agree
+                # bit-for-bit
+                K = K.at[row_j, ev.r].set(msg_i, mode="drop")
+                # scatter: last-write-wins
+                K = K.at[row_i, ev.s].set(msg_j, mode="drop")
 
             # --- update: endpoints that received a message recompute
             # Eq. (6) via the shared per-shard step
             # (core.sparse.batched_model_update — the same function the
             # partitioned engine applies to local rows)
-            new = batched_model_update(tabs.nbr_p[upd], K[upd], c[upd],
-                                       theta_sol[upd], alpha)
-            # scatter: idempotent — duplicate agents in upd recompute the
-            # same row from the same post-communication K
-            theta = theta.at[jnp.where(got, upd, n)].set(new, mode="drop")
+            with spans.phase("update"):
+                new = batched_model_update(tabs.nbr_p[upd], K[upd], c[upd],
+                                           theta_sol[upd], alpha)
+                # scatter: idempotent — duplicate agents in upd recompute
+                # the same row from the same post-communication K
+                theta = theta.at[jnp.where(got, upd, n)].set(new,
+                                                             mode="drop")
 
-        delivered = delivered + jnp.sum(ev.deliver_ij) + jnp.sum(ev.deliver_ji)
-        dropped = dropped + jnp.sum(ev.valid & ~ev.deliver_ij) \
-            + jnp.sum(ev.valid & ~ev.deliver_ji)
-        invalid = invalid + jnp.sum(~ev.valid)
-        active = active2 if fused \
-            else sched.churn_step(k_churn, conditions, active)
+        with spans.phase("draw"):
+            delivered = delivered + jnp.sum(ev.deliver_ij) \
+                + jnp.sum(ev.deliver_ji)
+            dropped = dropped + jnp.sum(ev.valid & ~ev.deliver_ij) \
+                + jnp.sum(ev.valid & ~ev.deliver_ji)
+            invalid = invalid + jnp.sum(~ev.valid)
+            active = active2 if fused \
+                else sched.churn_step(k_churn, conditions, active)
         if tel:
-            stale, updates, d_link, d_churn, d_part = tstate
-            stale = tmetrics.staleness_step(stale, got, upd, n)
-            updates = updates + jnp.sum(got)
-            link, churn, part = tmetrics.batch_drop_causes(
-                ev.deliver_ij, ev.deliver_ji, ev.valid, ev.cut, ev.dead)
-            tstate = (stale, updates, d_link + link, d_churn + churn,
-                      d_part + part)
+            with spans.phase("record"):
+                stale, updates, d_link, d_churn, d_part = tstate
+                stale = tmetrics.staleness_step(stale, got, upd, n)
+                updates = updates + jnp.sum(got)
+                link, churn, part = tmetrics.batch_drop_causes(
+                    ev.deliver_ij, ev.deliver_ji, ev.valid, ev.cut, ev.dead)
+                tstate = (stale, updates, d_link + link, d_churn + churn,
+                          d_part + part)
         if fused:
             # --- finish round t+1's prefetch: its stale-message gather ran
             # pre-scatter (above); the fresh-model and k_old gathers must
             # run here, *after* this round's scatters (the placement the
             # pipelined layout exists for)
-            pf = (ev2,) + round_prefetch(
-                theta, theta_in, K, ev2.i, ev2.j, ev2.s, ev2.r,
-                ev2.deliver_ij, ev2.deliver_ji, ev2.stale_ij, ev2.stale_ji,
-                stale_src=stale_src, no_stale=no_stale) + (k_churn2,)
+            with spans.phase("comm"):
+                pf = (ev2,) + round_prefetch(
+                    theta, theta_in, K, ev2.i, ev2.j, ev2.s, ev2.r,
+                    ev2.deliver_ij, ev2.deliver_ji, ev2.stale_ij,
+                    ev2.stale_ji, stale_src=stale_src,
+                    no_stale=no_stale) + (k_churn2,)
             base = (theta, K, pf, active, delivered, dropped, invalid,
                     got_ever)
         else:
@@ -385,19 +402,21 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
 
     def outer(carry, inp):
         ks, t0 = inp
-        inner_ts = t0 + jnp.arange(record_every)
+        with spans.phase("draw"):
+            inner_ts = t0 + jnp.arange(record_every)
         carry, _ = jax.lax.scan(round_fn, carry, (inner_ts, ks))
-        frac = jnp.mean(carry[3].astype(jnp.float32))
-        if tel:
-            theta, K = carry[0], carry[1]
-            if fused:
-                K = decode_slots(K, km)
-            obj = tmetrics.mp_local_objective(theta, K, tabs.nbr_p, c,
-                                              theta_sol, alpha)
-            stale, updates, d_link, d_churn, d_part = carry[8 if fused
-                                                            else 7:]
-            return carry, (theta, frac, obj, stale, updates, carry[4],
-                           d_link, d_churn, d_part, carry[6])
+        with spans.phase("record"):
+            frac = jnp.mean(carry[3].astype(jnp.float32))
+            if tel:
+                theta, K = carry[0], carry[1]
+                if fused:
+                    K = decode_slots(K, km)
+                obj = tmetrics.mp_local_objective(theta, K, tabs.nbr_p, c,
+                                                  theta_sol, alpha)
+                stale, updates, d_link, d_churn, d_part = \
+                    carry[8 if fused else 7:]
+                return carry, (theta, frac, obj, stale, updates, carry[4],
+                               d_link, d_churn, d_part, carry[6])
         return carry, (carry[0], frac)
 
     carry, outs = jax.lax.scan(outer, carry0, (keys, ts))
@@ -436,45 +455,53 @@ def run_mp_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
     trajectory agrees to fp rounding (not bit-for-bit); ``backend=None``
     keeps the historic program exactly.
     """
-    tabs = topo.device_tables()
     n = topo.n
-    theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(n, -1)
-    c = jnp.asarray(c, jnp.float32)
-    part_half = jnp.asarray(topo.partition_halves())
-    key = jax.random.PRNGKey(seed)
-    key, k_strag = jax.random.split(key)
-    rates = sched.straggler_rates(k_strag, conditions, n)
-
     record_every, n_rec = record_chunks(rounds, record_every)
     tel = telemetry_on(telemetry)
-
-    keys = jax.random.split(key, n_rec * record_every).reshape(
-        n_rec, record_every, 2)
-    ts = jnp.asarray((np.arange(n_rec) * record_every).astype(np.int32))
-    counters, outs = _scenario_scan(
-        tabs, part_half, rates, theta_sol, c, keys, ts,
-        conditions=conditions, alpha=alpha, batch=batch,
-        record_every=record_every, n_rec=n_rec, tel=tel, backend=backend)
-    delivered, dropped, invalid = counters
-    total_rounds = n_rec * record_every
-    frames = None
-    if tel:
-        (hist, active_hist, obj_h, stale_h, upd_h, del_h, link_h, churn_h,
-         part_h, inv_h) = outs
-        frames = TelemetryFrames(
-            rounds=(np.arange(n_rec) + 1) * record_every,
-            objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
-            updates=np.asarray(upd_h, np.int64),
-            delivered=np.asarray(del_h, np.int64),
-            drop_link=np.asarray(link_h, np.int64),
-            drop_churn=np.asarray(churn_h, np.int64),
-            drop_partition=np.asarray(part_h, np.int64),
-            invalid=np.asarray(inv_h, np.int64))
-    else:
-        hist, active_hist = outs
-    return SimTrace(np.asarray(hist), np.asarray(active_hist),
-                    int(delivered), int(dropped), total_rounds,
-                    total_rounds * batch, int(invalid), telemetry=frames)
+    with spans.host("prepare") as span:
+        halves = topo.partition_halves()
+        ts = (np.arange(n_rec) * record_every).astype(np.int32)
+        span.set_metadata(bytes=spans.upload_bytes(
+            topo.tables, theta_sol, c, halves, ts))
+        tabs = topo.device_tables()
+        theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(n, -1)
+        c = jnp.asarray(c, jnp.float32)
+        part_half = jnp.asarray(halves)
+        key = jax.random.PRNGKey(seed)
+        key, k_strag = jax.random.split(key)
+        rates = sched.straggler_rates(k_strag, conditions, n)
+        keys = jax.random.split(key, n_rec * record_every).reshape(
+            n_rec, record_every, 2)
+        ts = jnp.asarray(ts)
+    with spans.host("scan"):
+        counters, outs = _scenario_scan(
+            tabs, part_half, rates, theta_sol, c, keys, ts,
+            conditions=conditions, alpha=alpha, batch=batch,
+            record_every=record_every, n_rec=n_rec, tel=tel,
+            backend=backend)
+    with spans.host("wait"):
+        jax.block_until_ready((counters, outs))
+    with spans.host("fetch", bytes=spans.fetch_bytes(counters, outs)):
+        delivered, dropped, invalid = counters
+        total_rounds = n_rec * record_every
+        frames = None
+        if tel:
+            (hist, active_hist, obj_h, stale_h, upd_h, del_h, link_h,
+             churn_h, part_h, inv_h) = outs
+            frames = TelemetryFrames(
+                rounds=(np.arange(n_rec) + 1) * record_every,
+                objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
+                updates=np.asarray(upd_h, np.int64),
+                delivered=np.asarray(del_h, np.int64),
+                drop_link=np.asarray(link_h, np.int64),
+                drop_churn=np.asarray(churn_h, np.int64),
+                drop_partition=np.asarray(part_h, np.int64),
+                invalid=np.asarray(inv_h, np.int64))
+        else:
+            hist, active_hist = outs
+        return SimTrace(np.asarray(hist), np.asarray(active_hist),
+                        int(delivered), int(dropped), total_rounds,
+                        total_rounds * batch, int(invalid), telemetry=frames)
 
 
 # ---------------------------------------------------------------------------
@@ -631,6 +658,19 @@ def _reshape_stream(stream: EventStream, n_rec: int, record_every: int):
         stream._replace(active_frac=None))
 
 
+def _fetch_stream_totals(stream, n_rec: int, record_every: int) -> tuple:
+    """(delivered, dropped, invalid, active_hist) of a replayed stream,
+    copied back under their own ``fetch`` span (they are ready before the
+    scan that replays the stream is)."""
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            stream.deliver_ij, stream.deliver_ji, stream.valid,
+            stream.active_frac)):
+        totals = stream_totals(stream)
+        active_hist = np.asarray(stream.active_frac).reshape(
+            n_rec, record_every)[:, -1]
+    return totals + (active_hist,)
+
+
 @partial(jax.jit, static_argnames=("mu", "rho", "backend", "tel", "primal"))
 def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
                       tel_args=(), xym=(), *, mu: float, rho: float,
@@ -677,32 +717,34 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
     """
     n, k = nbr_w.shape
     if not isinstance(state0, SparseADMMState):
-        state0 = _warm_admm_state(*state0)
+        with spans.phase("init"):
+            state0 = _warm_admm_state(*state0)
     edge_fn = resolve("cl_edge_step", backend)
 
     def round_fn(carry, ev_t):
         st, pub_prev, *tstate = carry
         # --- primal phase: endpoints whose incoming payload was delivered
-        upd = jnp.concatenate([ev_t.i, ev_t.j])                    # (2B,)
-        got = jnp.concatenate([ev_t.deliver_ji, ev_t.deliver_ij])
-        live_rows = jnp.arange(k)[None, :] < deg_count[upd][:, None]
-        if primal is None:
-            new_theta, theta_js = batched_admm_primal(
-                nbr_w[upd], live_rows, st.Z_own[upd], st.Z_nbr[upd],
-                st.L_own[upd], st.L_nbr[upd], D[upd], m_counts[upd],
-                sx[upd], mu, rho, backend)
-        else:
-            xr = tuple(a[upd] for a in xym) if primal.needs_data else ()
-            new_theta, theta_js = primal.solve_batch(
-                nbr_w[upd], live_rows, st.Z_own[upd], st.Z_nbr[upd],
-                st.L_own[upd], st.L_nbr[upd], D[upd], m_counts[upd],
-                sx[upd], xr, st.theta[upd], mu, rho, backend)
-        new_K = jnp.where(live_rows[:, :, None], theta_js, st.K[upd])
-        rowu = jnp.where(got, upd, n)
-        # scatter: idempotent — duplicate agents in upd derive identical
-        # rows from the same round-start Z/L state
-        theta = st.theta.at[rowu].set(new_theta, mode="drop")
-        K = st.K.at[rowu].set(new_K, mode="drop")  # scatter: idempotent
+        with spans.phase("primal"):
+            upd = jnp.concatenate([ev_t.i, ev_t.j])                # (2B,)
+            got = jnp.concatenate([ev_t.deliver_ji, ev_t.deliver_ij])
+            live_rows = jnp.arange(k)[None, :] < deg_count[upd][:, None]
+            if primal is None:
+                new_theta, theta_js = batched_admm_primal(
+                    nbr_w[upd], live_rows, st.Z_own[upd], st.Z_nbr[upd],
+                    st.L_own[upd], st.L_nbr[upd], D[upd], m_counts[upd],
+                    sx[upd], mu, rho, backend)
+            else:
+                xr = tuple(a[upd] for a in xym) if primal.needs_data else ()
+                new_theta, theta_js = primal.solve_batch(
+                    nbr_w[upd], live_rows, st.Z_own[upd], st.Z_nbr[upd],
+                    st.L_own[upd], st.L_nbr[upd], D[upd], m_counts[upd],
+                    sx[upd], xr, st.theta[upd], mu, rho, backend)
+            new_K = jnp.where(live_rows[:, :, None], theta_js, st.K[upd])
+            rowu = jnp.where(got, upd, n)
+            # scatter: idempotent — duplicate agents in upd derive
+            # identical rows from the same round-start Z/L state
+            theta = st.theta.at[rowu].set(new_theta, mode="drop")
+            K = st.K.at[rowu].set(new_K, mode="drop")  # scatter: idempotent
 
         # --- publish: post-primal models, round-start duals
         pub = (theta, K, st.L_own, st.L_nbr)
@@ -711,44 +753,48 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
         # fused op (kernels/round_fuse.cl_edge_step — auto resolves the
         # expression-identical XLA form, so the trajectory is bit-for-bit
         # the inline code's)
-        own_s = jnp.concatenate([ev_t.s, ev_t.r])
-        oth_a = jnp.concatenate([ev_t.j, ev_t.i])
-        oth_s = jnp.concatenate([ev_t.r, ev_t.s])
-        stale = jnp.concatenate([ev_t.stale_ji, ev_t.stale_ij])
-        Z_own, Z_nbr, L_own, L_nbr = edge_fn(
-            theta, K, st.Z_own, st.Z_nbr, st.L_own, st.L_nbr, *pub_prev,
-            upd, own_s, oth_a, oth_s, stale, got, rho=rho)
+        with spans.phase("edge"):
+            own_s = jnp.concatenate([ev_t.s, ev_t.r])
+            oth_a = jnp.concatenate([ev_t.j, ev_t.i])
+            oth_s = jnp.concatenate([ev_t.r, ev_t.s])
+            stale = jnp.concatenate([ev_t.stale_ji, ev_t.stale_ij])
+            Z_own, Z_nbr, L_own, L_nbr = edge_fn(
+                theta, K, st.Z_own, st.Z_nbr, st.L_own, st.L_nbr, *pub_prev,
+                upd, own_s, oth_a, oth_s, stale, got, rho=rho)
 
         st = SparseADMMState(theta, K, Z_own, Z_nbr, L_own, L_nbr)
         if tel:
-            stale, updates = tstate
-            stale = tmetrics.staleness_step(stale, got, upd, n)
-            updates = updates + jnp.sum(got)
-            tstate = (stale, updates)
+            with spans.phase("record"):
+                stale, updates = tstate
+                stale = tmetrics.staleness_step(stale, got, upd, n)
+                updates = updates + jnp.sum(got)
+                tstate = (stale, updates)
         return (st, pub, *tstate), None
 
     def outer(carry, ev_blk):
         carry, _ = jax.lax.scan(round_fn, carry, ev_blk)
         st = carry[0]
         if tel:
-            live = jnp.arange(k)[None, :] < deg_count[:, None]
-            if primal is not None and primal.needs_data:
-                loss_vec = primal.batch_local_loss(st.theta, *xym)
-                obj = tmetrics.cl_local_objective_from_loss(
-                    st.theta, st.K, nbr_w, live, D, loss_vec, mu)
-            else:
-                (sxx,) = tel_args
-                obj = tmetrics.cl_local_objective(st.theta, st.K, nbr_w,
-                                                  live, D, m_counts, sx,
-                                                  sxx, mu)
-            stale, updates = carry[2:]
-            return carry, (st.theta, obj, stale, updates)
+            with spans.phase("record"):
+                live = jnp.arange(k)[None, :] < deg_count[:, None]
+                if primal is not None and primal.needs_data:
+                    loss_vec = primal.batch_local_loss(st.theta, *xym)
+                    obj = tmetrics.cl_local_objective_from_loss(
+                        st.theta, st.K, nbr_w, live, D, loss_vec, mu)
+                else:
+                    (sxx,) = tel_args
+                    obj = tmetrics.cl_local_objective(
+                        st.theta, st.K, nbr_w, live, D, m_counts, sx, sxx,
+                        mu)
+                stale, updates = carry[2:]
+                return carry, (st.theta, obj, stale, updates)
         return carry, st.theta
 
-    pub0 = (state0.theta, state0.K, state0.L_own, state0.L_nbr)
-    carry0 = (state0, pub0)
-    if tel:
-        carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0))
+    with spans.phase("init"):
+        pub0 = (state0.theta, state0.K, state0.L_own, state0.L_nbr)
+        carry0 = (state0, pub0)
+        if tel:
+            carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0))
     carry, hist = jax.lax.scan(outer, carry0, ev)
     return carry[0], hist
 
@@ -783,61 +829,75 @@ def run_cl_scenario(topo: SparseTopology, data: AgentData, mu: float,
     ``core.primal.solitary_adamw``), which fix the slot-row width p
     independently of the feature dimension of ``data.x``.
     """
-    tabs = topo.device_tables()
     n = topo.n
     record_every, n_rec = record_chunks(rounds, record_every)
     total_rounds = n_rec * record_every
-    if state is None:
-        if theta_sol is None:
-            raise ValueError("need theta_sol (warm start) or explicit state")
-        # warm start built inside the jitted scan (see _cl_scenario_scan)
-        state = (tabs.nbr_idx,
-                 jnp.asarray(theta_sol, jnp.float32).reshape(n, -1))
-    if stream is None:
-        stream = precompute_event_stream(
-            tabs, jnp.asarray(topo.partition_halves()), conditions, batch,
-            seed, total_rounds)
-    else:
-        if stream.i.shape[0] != total_rounds:
-            raise ValueError(
-                f"stream covers {stream.i.shape[0]} rounds but the clamped "
-                f"horizon is {total_rounds}")
-        batch = int(stream.i.shape[1])
-
-    D = jnp.asarray(tabs.deg_w, jnp.float32)
-    mask = jnp.asarray(data.mask, jnp.float32)
-    x = jnp.asarray(data.x, jnp.float32)
-    m_counts = jnp.sum(mask, axis=1)
-    sx = jnp.sum(x * mask[:, :, None], axis=1)
+    if state is None and theta_sol is None:
+        raise ValueError("need theta_sol (warm start) or explicit state")
+    if stream is not None and stream.i.shape[0] != total_rounds:
+        raise ValueError(
+            f"stream covers {stream.i.shape[0]} rounds but the clamped "
+            f"horizon is {total_rounds}")
     needs_data = primal is not None and primal.needs_data
-    xym = (x, jnp.asarray(data.y, jnp.float32), mask) if needs_data else ()
     tel = telemetry_on(telemetry)
-    tel_args = ()
-    if tel and not needs_data:
-        # the quadratic objective's sufficient statistic; data-hungry
-        # solvers evaluate their loss directly from xym instead
-        sxx = jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1)
-        tel_args = (sxx,)
+    with spans.host("prepare") as span:
+        halves = topo.partition_halves() if stream is None else None
+        span.set_metadata(bytes=spans.upload_bytes(
+            topo.tables, state, halves, data.x, data.mask,
+            data.y if needs_data else None,
+            theta_sol if state is None else None))
+        tabs = topo.device_tables()
+        if state is None:
+            # warm start built inside the jitted scan (see
+            # _cl_scenario_scan)
+            state = (tabs.nbr_idx,
+                     jnp.asarray(theta_sol, jnp.float32).reshape(n, -1))
+        if stream is None:
+            stream = precompute_event_stream(
+                tabs, jnp.asarray(halves), conditions, batch, seed,
+                total_rounds)
+        else:
+            batch = int(stream.i.shape[1])
 
-    ev = _reshape_stream(stream, n_rec, record_every)
-    st, hist = _cl_scenario_scan(
-        tabs.nbr_w, tabs.deg_count, D, m_counts, sx, state, ev, tel_args,
-        xym, mu=mu, rho=rho, backend=backend, tel=tel, primal=primal)
-    delivered, dropped, invalid = stream_totals(stream)
-    active_hist = np.asarray(stream.active_frac).reshape(
-        n_rec, record_every)[:, -1]
-    frames = None
-    if tel:
-        hist, obj_h, stale_h, upd_h = hist
-        frames = TelemetryFrames(
-            rounds=(np.arange(n_rec) + 1) * record_every,
-            objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
-            updates=np.asarray(upd_h, np.int64),
-            **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
-    return CLSimTrace(theta_hist=np.asarray(hist), active_hist=active_hist,
-                      delivered=delivered, dropped=dropped,
-                      rounds=total_rounds, events=total_rounds * batch,
-                      invalid=invalid, final=st, telemetry=frames)
+        D = jnp.asarray(tabs.deg_w, jnp.float32)
+        mask = jnp.asarray(data.mask, jnp.float32)
+        x = jnp.asarray(data.x, jnp.float32)
+        m_counts = jnp.sum(mask, axis=1)
+        sx = jnp.sum(x * mask[:, :, None], axis=1)
+        xym = (x, jnp.asarray(data.y, jnp.float32), mask) if needs_data \
+            else ()
+        tel_args = ()
+        if tel and not needs_data:
+            # the quadratic objective's sufficient statistic; data-hungry
+            # solvers evaluate their loss directly from xym instead
+            sxx = jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1)
+            tel_args = (sxx,)
+
+        ev = _reshape_stream(stream, n_rec, record_every)
+    with spans.host("scan"):
+        st, hist = _cl_scenario_scan(
+            tabs.nbr_w, tabs.deg_count, D, m_counts, sx, state, ev,
+            tel_args, xym, mu=mu, rho=rho, backend=backend, tel=tel,
+            primal=primal)
+    delivered, dropped, invalid, active_hist = _fetch_stream_totals(
+        stream, n_rec, record_every)
+    with spans.host("wait"):
+        jax.block_until_ready((st, hist))
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            hist, (stream.cut, stream.dead) if tel else None)):
+        frames = None
+        if tel:
+            hist, obj_h, stale_h, upd_h = hist
+            frames = TelemetryFrames(
+                rounds=(np.arange(n_rec) + 1) * record_every,
+                objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
+                updates=np.asarray(upd_h, np.int64),
+                **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
+        return CLSimTrace(theta_hist=np.asarray(hist),
+                          active_hist=active_hist, delivered=delivered,
+                          dropped=dropped, rounds=total_rounds,
+                          events=total_rounds * batch, invalid=invalid,
+                          final=st, telemetry=frames)
 
 
 # ---------------------------------------------------------------------------
@@ -897,33 +957,36 @@ def _joint_scenario_scan(w0, live0, theta0, K0, c, theta_sol, ev, ts, *,
         ev_t, t = inp
 
         # --- communication: all scatters land before any update reads
-        msg_i = jnp.where(ev_t.stale_ij[:, None], theta_prev[ev_t.i],
-                          theta[ev_t.i])
-        msg_j = jnp.where(ev_t.stale_ji[:, None], theta_prev[ev_t.j],
-                          theta[ev_t.j])
-        if prune:
-            ok_ij = ev_t.deliver_ij & live[ev_t.j, ev_t.r]
-            ok_ji = ev_t.deliver_ji & live[ev_t.i, ev_t.s]
-            suppressed = suppressed \
-                + jnp.sum(ev_t.deliver_ij & ~ok_ij) \
-                + jnp.sum(ev_t.deliver_ji & ~ok_ji)
-        else:
-            ok_ij, ok_ji = ev_t.deliver_ij, ev_t.deliver_ji
-        row_j = jnp.where(ok_ij, ev_t.j, n)
-        row_i = jnp.where(ok_ji, ev_t.i, n)
-        # scatter: last-write-wins — a repeated edge in one batch lands the
-        # batch-order winner (same policy as the scenario engine above)
-        K = K.at[row_j, ev_t.r].set(msg_i, mode="drop")
-        K = K.at[row_i, ev_t.s].set(msg_j, mode="drop")  # scatter: last-write-wins
+        with spans.phase("comm"):
+            msg_i = jnp.where(ev_t.stale_ij[:, None], theta_prev[ev_t.i],
+                              theta[ev_t.i])
+            msg_j = jnp.where(ev_t.stale_ji[:, None], theta_prev[ev_t.j],
+                              theta[ev_t.j])
+            if prune:
+                ok_ij = ev_t.deliver_ij & live[ev_t.j, ev_t.r]
+                ok_ji = ev_t.deliver_ji & live[ev_t.i, ev_t.s]
+                suppressed = suppressed \
+                    + jnp.sum(ev_t.deliver_ij & ~ok_ij) \
+                    + jnp.sum(ev_t.deliver_ji & ~ok_ji)
+            else:
+                ok_ij, ok_ji = ev_t.deliver_ij, ev_t.deliver_ji
+            row_j = jnp.where(ok_ij, ev_t.j, n)
+            row_i = jnp.where(ok_ji, ev_t.i, n)
+            # scatter: last-write-wins — a repeated edge in one batch lands
+            # the batch-order winner (same policy as the scenario engine
+            # above)
+            K = K.at[row_j, ev_t.r].set(msg_i, mode="drop")
+            K = K.at[row_i, ev_t.s].set(msg_j, mode="drop")  # scatter: last-write-wins
 
         # --- update: Eq. (6) under the current learned weights
-        upd = jnp.concatenate([ev_t.i, ev_t.j])
-        got = jnp.concatenate([ok_ji, ok_ij])
-        new = batched_model_update(w[upd], K[upd], c[upd], theta_sol[upd],
-                                   alpha, backend)
-        # scatter: idempotent — duplicate agents in upd recompute the same
-        # row from the same post-communication K
-        theta = theta.at[jnp.where(got, upd, n)].set(new, mode="drop")
+        with spans.phase("update"):
+            upd = jnp.concatenate([ev_t.i, ev_t.j])
+            got = jnp.concatenate([ok_ji, ok_ij])
+            new = batched_model_update(w[upd], K[upd], c[upd],
+                                       theta_sol[upd], alpha, backend)
+            # scatter: idempotent — duplicate agents in upd recompute the
+            # same row from the same post-communication K
+            theta = theta.at[jnp.where(got, upd, n)].set(new, mode="drop")
 
         # --- graph step (compiled out entirely at rate 0)
         if eta_graph > 0.0:
@@ -934,27 +997,32 @@ def _joint_scenario_scan(w0, live0, theta0, K0, c, theta_sol, ev, ts, *,
                     return prune_rows(w2, live, prune_eps)
                 return w2, live
 
-            w, live = jax.lax.cond(
-                (t + 1) % graph_every == 0, do_graph,
-                lambda w, live: (w, live), w, live)
+            with spans.phase("graph"):
+                w, live = jax.lax.cond(
+                    (t + 1) % graph_every == 0, do_graph,
+                    lambda w, live: (w, live), w, live)
 
         if tel:
-            stale, updates = tstate
-            stale = tmetrics.staleness_step(stale, got, upd, n)
-            updates = updates + jnp.sum(got)
-            tstate = (stale, updates)
+            with spans.phase("record"):
+                stale, updates = tstate
+                stale = tmetrics.staleness_step(stale, got, upd, n)
+                updates = updates + jnp.sum(got)
+                tstate = (stale, updates)
         return (theta, K, theta_in, w, live, suppressed, *tstate), None
 
     def outer(carry, inp):
         carry, _ = jax.lax.scan(round_fn, carry, inp)
         theta, K, _, w, live, suppressed, *tstate = carry
-        edges = jnp.sum(live & (w > 0))
-        if tel:
-            # objective under the *learned* weights (pruned slots weigh 0)
-            obj = tmetrics.mp_local_objective(
-                theta, K, jnp.where(live, w, 0.0), c, theta_sol, alpha)
-            stale, updates = tstate
-            return carry, (theta, edges, obj, stale, updates, suppressed)
+        with spans.phase("record"):
+            edges = jnp.sum(live & (w > 0))
+            if tel:
+                # objective under the *learned* weights (pruned slots
+                # weigh 0)
+                obj = tmetrics.mp_local_objective(
+                    theta, K, jnp.where(live, w, 0.0), c, theta_sol, alpha)
+                stale, updates = tstate
+                return carry, (theta, edges, obj, stale, updates,
+                               suppressed)
         return carry, (theta, edges)
 
     carry0 = (theta0, K0, theta0, w0, live0, jnp.int32(0))
@@ -991,53 +1059,62 @@ def run_joint_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
     identical event schedule (the graph step is compiled out).  The horizon
     follows the shared recording policy (``core.sparse.record_chunks``).
     """
-    tabs = topo.device_tables()
     n = topo.n
-    theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(n, -1)
-    c = jnp.asarray(c, jnp.float32)
     record_every, n_rec = record_chunks(rounds, record_every)
     total_rounds = n_rec * record_every
-    if stream is None:
-        stream = precompute_event_stream(
-            tabs, jnp.asarray(topo.partition_halves()), conditions, batch,
-            seed, total_rounds)
-    else:
-        if stream.i.shape[0] != total_rounds:
-            raise ValueError(
-                f"stream covers {stream.i.shape[0]} rounds but the clamped "
-                f"horizon is {total_rounds}")
-        batch = int(stream.i.shape[1])
-
-    theta0, K0 = _mp_warm_start(tabs, theta_sol)
-    w0 = tabs.nbr_p
-    live0 = live_slots(tabs.deg_count, topo.k_max)
+    if stream is not None and stream.i.shape[0] != total_rounds:
+        raise ValueError(
+            f"stream covers {stream.i.shape[0]} rounds but the clamped "
+            f"horizon is {total_rounds}")
     tel = telemetry_on(telemetry)
-    ev = _reshape_stream(stream, n_rec, record_every)
-    ts = jnp.arange(total_rounds, dtype=jnp.int32).reshape(
-        n_rec, record_every)
-    carry, outs = _joint_scenario_scan(
-        w0, live0, theta0, K0, c, theta_sol, ev, ts, alpha=alpha,
-        eta_graph=eta_graph, lam=lam, graph_every=graph_every,
-        prune_eps=prune_eps, backend=backend, tel=tel)
+    with spans.host("prepare") as span:
+        halves = topo.partition_halves() if stream is None else None
+        span.set_metadata(bytes=spans.upload_bytes(
+            topo.tables, theta_sol, c, halves))
+        tabs = topo.device_tables()
+        theta_sol = jnp.asarray(theta_sol, jnp.float32).reshape(n, -1)
+        c = jnp.asarray(c, jnp.float32)
+        if stream is None:
+            stream = precompute_event_stream(
+                tabs, jnp.asarray(halves), conditions, batch, seed,
+                total_rounds)
+        else:
+            batch = int(stream.i.shape[1])
+
+        theta0, K0 = _mp_warm_start(tabs, theta_sol)
+        w0 = tabs.nbr_p
+        live0 = live_slots(tabs.deg_count, topo.k_max)
+        ev = _reshape_stream(stream, n_rec, record_every)
+        ts = jnp.arange(total_rounds, dtype=jnp.int32).reshape(
+            n_rec, record_every)
+    with spans.host("scan"):
+        carry, outs = _joint_scenario_scan(
+            w0, live0, theta0, K0, c, theta_sol, ev, ts, alpha=alpha,
+            eta_graph=eta_graph, lam=lam, graph_every=graph_every,
+            prune_eps=prune_eps, backend=backend, tel=tel)
+    delivered, dropped, invalid, active_hist = _fetch_stream_totals(
+        stream, n_rec, record_every)
+    with spans.host("wait"):
+        jax.block_until_ready((carry, outs))
     theta, K, _, w, live, suppressed = carry[:6]
-    delivered, dropped, invalid = stream_totals(stream)
-    active_hist = np.asarray(stream.active_frac).reshape(
-        n_rec, record_every)[:, -1]
-    frames = None
-    if tel:
-        hist, live_hist, obj_h, stale_h, upd_h, sup_h = outs
-        frames = TelemetryFrames(
-            rounds=(np.arange(n_rec) + 1) * record_every,
-            objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
-            updates=np.asarray(upd_h, np.int64),
-            suppressed=np.asarray(sup_h, np.int64),
-            **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
-    else:
-        hist, live_hist = outs
-    return JointSimTrace(
-        theta_hist=np.asarray(hist), active_hist=active_hist,
-        delivered=delivered, dropped=dropped, rounds=total_rounds,
-        events=total_rounds * batch, invalid=invalid,
-        final_w=np.asarray(w), final_live=np.asarray(live),
-        live_edges_hist=np.asarray(live_hist), suppressed=int(suppressed),
-        telemetry=frames)
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            outs, w, live, suppressed,
+            (stream.cut, stream.dead) if tel else None)):
+        frames = None
+        if tel:
+            hist, live_hist, obj_h, stale_h, upd_h, sup_h = outs
+            frames = TelemetryFrames(
+                rounds=(np.arange(n_rec) + 1) * record_every,
+                objective=np.asarray(obj_h), staleness=np.asarray(stale_h),
+                updates=np.asarray(upd_h, np.int64),
+                suppressed=np.asarray(sup_h, np.int64),
+                **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
+        else:
+            hist, live_hist = outs
+        return JointSimTrace(
+            theta_hist=np.asarray(hist), active_hist=active_hist,
+            delivered=delivered, dropped=dropped, rounds=total_rounds,
+            events=total_rounds * batch, invalid=invalid,
+            final_w=np.asarray(w), final_live=np.asarray(live),
+            live_edges_hist=np.asarray(live_hist),
+            suppressed=int(suppressed), telemetry=frames)

@@ -61,9 +61,11 @@ from repro.launch.sim_mesh import (AGENT_AXIS, HaloCodec, agent_sharding,
 from repro.telemetry import metrics as tmetrics
 from repro.telemetry.config import TelemetryConfig, telemetry_on
 from repro.telemetry.frames import TelemetryFrames
-from .engines import SimTrace, _reshape_stream, warm_admm_from_copies
+from .engines import (SimTrace, _fetch_stream_totals, _reshape_stream,
+                      warm_admm_from_copies)
+from . import spans
 from .scheduler import (EventStream, NetworkConditions,
-                        precompute_event_stream, stream_totals)
+                        precompute_event_stream)
 from .topology import SparseTopology
 
 
@@ -381,6 +383,17 @@ def _partition_args(mesh, part: GraphPartition) -> dict:
                 halo_src_pos=_on_shards(mesh, part.halo_src_pos))
 
 
+def _upload(mesh, part: GraphPartition, rows: dict) -> tuple:
+    """Place host rows (stacked by ``part.shard_rows``) and the partition's
+    halo tables on the mesh.  Returns the scan's keyword arguments and the
+    bytes uploaded."""
+    args = {k: _on_shards(mesh, v) for k, v in rows.items()}
+    pargs = _partition_args(mesh, part)
+    return dict(args, **pargs), spans.upload_bytes(
+        rows, part.fetch, part.bnd_pos, part.halo_src_shard,
+        part.halo_src_pos)
+
+
 def _local_capacities(batch: int, P_: int, local_batch) -> tuple:
     """Per-shard static (event, update) capacities ``(E, U)`` — the
     8-sigma defaults, or the lossless explicit-capacity override."""
@@ -469,66 +482,76 @@ def _sharded_scenario_scan(mesh, stream, theta0, K0, nbr_p, c, sol,
 
         def round_fn(carry, ev_t):
             theta, K, ext_prev, overflow, *tstate = carry
-            ext = exchange_halo(theta)
+            with spans.phase("exchange"):
+                ext = exchange_halo(theta)
 
             # --- compact to the events touching this shard: everything
             # below (message gathers, slot scatters, updates) then runs at
             # O(E) ~ 2B/P instead of O(B) per shard
-            rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
-            sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
-            i = _take_padded(ev_t.i, sel, 0)
-            j = _take_padded(ev_t.j, sel, 0)
-            s = _take_padded(ev_t.s, sel, 0)
-            r = _take_padded(ev_t.r, sel, 0)
-            d_ij = _take_padded(ev_t.deliver_ij, sel, False)
-            d_ji = _take_padded(ev_t.deliver_ji, sel, False)
-            st_ij = _take_padded(ev_t.stale_ij, sel, False)
-            st_ji = _take_padded(ev_t.stale_ji, sel, False)
-            overflow += jnp.maximum(jnp.sum(rel) - E, 0)
+            with spans.phase("draw"):
+                rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
+                sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
+                i = _take_padded(ev_t.i, sel, 0)
+                j = _take_padded(ev_t.j, sel, 0)
+                s = _take_padded(ev_t.s, sel, 0)
+                r = _take_padded(ev_t.r, sel, 0)
+                d_ij = _take_padded(ev_t.deliver_ij, sel, False)
+                d_ji = _take_padded(ev_t.deliver_ji, sel, False)
+                st_ij = _take_padded(ev_t.stale_ij, sel, False)
+                st_ji = _take_padded(ev_t.stale_ji, sel, False)
+                overflow += jnp.maximum(jnp.sum(rel) - E, 0)
 
             # --- communication: deliver into local receivers' slots
-            f_i, f_j = fetch_q[i], fetch_q[j]
-            msg_i = jnp.where(st_ij[:, None], ext_prev[f_i], ext[f_i])
-            msg_j = jnp.where(st_ji[:, None], ext_prev[f_j], ext[f_j])
-            row_j = jnp.where(d_ij & (f_j < m), f_j, m)
-            row_i = jnp.where(d_ji & (f_i < m), f_i, m)
-            # scatter: last-write-wins — a repeated edge in one batch lands
-            # the batch-order winner (mirrors the dense scenario engine)
-            K = K.at[row_j, r].set(msg_i, mode="drop")
-            K = K.at[row_i, s].set(msg_j, mode="drop")  # scatter: last-write-wins
+            with spans.phase("comm"):
+                f_i, f_j = fetch_q[i], fetch_q[j]
+                msg_i = jnp.where(st_ij[:, None], ext_prev[f_i], ext[f_i])
+                msg_j = jnp.where(st_ji[:, None], ext_prev[f_j], ext[f_j])
+                row_j = jnp.where(d_ij & (f_j < m), f_j, m)
+                row_i = jnp.where(d_ji & (f_i < m), f_i, m)
+                # scatter: last-write-wins — a repeated edge in one batch
+                # lands the batch-order winner (mirrors the dense scenario
+                # engine)
+                K = K.at[row_j, r].set(msg_i, mode="drop")
+                K = K.at[row_i, s].set(msg_j, mode="drop")  # scatter: last-write-wins
 
             # --- update: compact local endpoints, shared Eq. (6) step
-            f_u = jnp.concatenate([f_i, f_j])
-            got = jnp.concatenate([d_ji, d_ij]) & (f_u < m)
-            usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
-            lu = _take_padded(f_u, usel, m)
-            lu_c = jnp.minimum(lu, m - 1)
-            new = batched_model_update(nbr_p_blk[lu_c], K[lu_c], c_blk[lu_c],
-                                       sol_blk[lu_c], alpha)
-            # scatter: idempotent — duplicate rows in lu recompute the same
-            # value from the same post-communication K
-            theta = theta.at[jnp.where(lu < m, lu, m)].set(new, mode="drop")
-            overflow += jnp.maximum(jnp.sum(got) - U, 0)
+            with spans.phase("update"):
+                f_u = jnp.concatenate([f_i, f_j])
+                got = jnp.concatenate([d_ji, d_ij]) & (f_u < m)
+                usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
+                lu = _take_padded(f_u, usel, m)
+                lu_c = jnp.minimum(lu, m - 1)
+                new = batched_model_update(nbr_p_blk[lu_c], K[lu_c],
+                                           c_blk[lu_c], sol_blk[lu_c], alpha)
+                # scatter: idempotent — duplicate rows in lu recompute the
+                # same value from the same post-communication K
+                theta = theta.at[jnp.where(lu < m, lu, m)].set(new,
+                                                               mode="drop")
+                overflow += jnp.maximum(jnp.sum(got) - U, 0)
             if tel:
-                stale, updates = tstate
-                stale = tmetrics.staleness_step(stale, got, f_u, m)
-                updates = updates + jnp.sum(got)
-                tstate = (stale, updates)
+                with spans.phase("record"):
+                    stale, updates = tstate
+                    stale = tmetrics.staleness_step(stale, got, f_u, m)
+                    updates = updates + jnp.sum(got)
+                    tstate = (stale, updates)
             return (theta, K, ext, overflow, *tstate), None
 
         def outer(carry, ev_blk):
             carry, _ = jax.lax.scan(round_fn, carry, ev_blk)
             if tel:
-                obj = tmetrics.mp_local_objective(
-                    carry[0], carry[1], nbr_p_blk, c_blk, sol_blk, alpha)
-                stale, updates = carry[4:]
-                return carry, (carry[0], obj, stale, updates[None])
+                with spans.phase("record"):
+                    obj = tmetrics.mp_local_objective(
+                        carry[0], carry[1], nbr_p_blk, c_blk, sol_blk, alpha)
+                    stale, updates = carry[4:]
+                    return carry, (carry[0], obj, stale, updates[None])
             return carry, carry[0]
 
-        ext0 = exchange_halo(theta0_blk)                 # = warm-start halo
-        carry0 = (theta0_blk, K0_blk, ext0, jnp.int32(0))
-        if tel:
-            carry0 = carry0 + (jnp.zeros((m,), jnp.int32), jnp.int32(0))
+        with spans.phase("exchange"):
+            ext0 = exchange_halo(theta0_blk)             # = warm-start halo
+        with spans.phase("init"):
+            carry0 = (theta0_blk, K0_blk, ext0, jnp.int32(0))
+            if tel:
+                carry0 = carry0 + (jnp.zeros((m,), jnp.int32), jnp.int32(0))
         carry, hist = jax.lax.scan(outer, carry0, ev)
         theta, overflow = carry[0], carry[3]
         if tel:
@@ -576,55 +599,62 @@ def run_mp_scenario_sharded(topo: SparseTopology, theta_sol, c, alpha: float,
     or the lossy "bf16"/"int8" encodings with f32 accumulation); the
     telemetry ``halo_bytes`` column accounts the coded wire size.
     """
-    mesh, P_, assignment, part = _sharded_setup(
-        topo, n_shards, mesh, assignment, partition_seed)
+    with spans.host("prepare") as span:
+        mesh, P_, assignment, part = _sharded_setup(
+            topo, n_shards, mesh, assignment, partition_seed)
 
-    tabs = topo.tables
-    n = topo.n
-    theta_sol = np.asarray(theta_sol, np.float32).reshape(n, -1)
-    c = np.asarray(c, np.float32)
-    record_every, n_rec = record_chunks(rounds, record_every)
-    total_rounds = n_rec * record_every
+        tabs = topo.tables
+        n = topo.n
+        theta_sol = np.asarray(theta_sol, np.float32).reshape(n, -1)
+        c = np.asarray(c, np.float32)
+        record_every, n_rec = record_chunks(rounds, record_every)
+        total_rounds = n_rec * record_every
 
-    stream = precompute_event_stream(
-        topo.device_tables(), jnp.asarray(topo.partition_halves()),
-        conditions, batch, seed, total_rounds)
+        halves = topo.partition_halves()
+        stream = precompute_event_stream(
+            topo.device_tables(), jnp.asarray(halves), conditions, batch,
+            seed, total_rounds)
 
-    K0 = theta_sol[tabs.nbr_idx]                     # warm start (§3.2)
-    sharded = dict(
-        theta0=part.shard_rows(theta_sol), K0=part.shard_rows(K0),
-        nbr_p=part.shard_rows(tabs.nbr_p), c=part.shard_rows(c),
-        sol=part.shard_rows(theta_sol))
-    E, U = _local_capacities(batch, P_, local_batch)
+        K0 = theta_sol[tabs.nbr_idx]                 # warm start (§3.2)
+        sharded = dict(
+            theta0=part.shard_rows(theta_sol), K0=part.shard_rows(K0),
+            nbr_p=part.shard_rows(tabs.nbr_p), c=part.shard_rows(c),
+            sol=part.shard_rows(theta_sol))
+        E, U = _local_capacities(batch, P_, local_batch)
 
-    tel = telemetry_on(telemetry)
-    codec = resolve_halo_codec(halo_codec)
-    outs = _sharded_scenario_scan(
-        mesh, stream, **{k: _on_shards(mesh, v) for k, v in sharded.items()},
-        **_partition_args(mesh, part),
-        alpha=alpha, m=part.shard_size, H=part.halo_size,
-        E=E, U=U, n_rec=n_rec, record_every=record_every,
-        exchange=exchange, codec=codec, tel=tel)
-    frames = None
-    if tel:
-        hist, theta, overflow, obj_h, stale_h, upd_h = outs
-        frames = _sharded_frames(
-            part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
-            overflow,
-            payload_row_bytes=codec.row_nbytes((theta_sol.shape[1],)))
-    else:
-        hist, theta, overflow = outs
-
-    delivered, dropped, invalid = stream_totals(stream)
-    active_hist = np.asarray(stream.active_frac).reshape(
-        n_rec, record_every)[:, -1]
-    return ShardedSimTrace(
-        theta_hist=part.unshard_rows(np.asarray(hist)),
-        active_hist=active_hist, delivered=delivered, dropped=dropped,
-        rounds=total_rounds, events=total_rounds * batch, invalid=invalid,
-        telemetry=frames, n_shards=P_, edge_cut=part.edge_cut,
-        halo_size=part.halo_size, local_batch=U,
-        overflow=int(np.asarray(overflow).sum()))
+        tel = telemetry_on(telemetry)
+        codec = resolve_halo_codec(halo_codec)
+        args, up = _upload(mesh, part, sharded)
+        span.set_metadata(bytes=up + spans.upload_bytes(tabs, halves))
+    with spans.host("scan"):
+        outs = _sharded_scenario_scan(
+            mesh, stream, **args,
+            alpha=alpha, m=part.shard_size, H=part.halo_size,
+            E=E, U=U, n_rec=n_rec, record_every=record_every,
+            exchange=exchange, codec=codec, tel=tel)
+    delivered, dropped, invalid, active_hist = _fetch_stream_totals(
+        stream, n_rec, record_every)
+    with spans.host("wait"):
+        jax.block_until_ready(outs)
+    # the final theta (outs[1]) stays on the device
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            outs[:1], outs[2:], (stream.cut, stream.dead) if tel else None)):
+        frames = None
+        if tel:
+            hist, theta, overflow, obj_h, stale_h, upd_h = outs
+            frames = _sharded_frames(
+                part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
+                overflow,
+                payload_row_bytes=codec.row_nbytes((theta_sol.shape[1],)))
+        else:
+            hist, theta, overflow = outs
+        return ShardedSimTrace(
+            theta_hist=part.unshard_rows(np.asarray(hist)),
+            active_hist=active_hist, delivered=delivered, dropped=dropped,
+            rounds=total_rounds, events=total_rounds * batch,
+            invalid=invalid, telemetry=frames, n_shards=P_,
+            edge_cut=part.edge_cut, halo_size=part.halo_size, local_batch=U,
+            overflow=int(np.asarray(overflow).sum()))
 
 
 # ---------------------------------------------------------------------------
@@ -676,7 +706,8 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
         hsrc, hpos = hsrc_blk[0], hpos_blk[0]
         exchange_halo = halo_exchange_fn(bnd, hsrc, hpos, H, P_, exchange,
                                          codec=codec)
-        live_blk = jnp.arange(k)[None, :] < degc_blk[:, None]      # (m, k)
+        with spans.phase("init"):
+            live_blk = jnp.arange(k)[None, :] < degc_blk[:, None]  # (m, k)
 
         def publish(theta, K, Lo, Ln):
             """Stacked payload rows [theta | K | L_own | L_nbr] -> ext."""
@@ -687,103 +718,117 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
             theta, K, Zo, Zn, Lo, Ln, ext_prev, overflow, *tstate = carry
 
             # --- compact to the events touching this shard (O(E) ~ 2B/P)
-            rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
-            sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
-            i = _take_padded(ev_t.i, sel, 0)
-            j = _take_padded(ev_t.j, sel, 0)
-            s = _take_padded(ev_t.s, sel, 0)
-            r = _take_padded(ev_t.r, sel, 0)
-            d_ij = _take_padded(ev_t.deliver_ij, sel, False)
-            d_ji = _take_padded(ev_t.deliver_ji, sel, False)
-            st_ij = _take_padded(ev_t.stale_ij, sel, False)
-            st_ji = _take_padded(ev_t.stale_ji, sel, False)
-            overflow += jnp.maximum(jnp.sum(rel) - E, 0)
+            with spans.phase("draw"):
+                rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
+                sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
+                i = _take_padded(ev_t.i, sel, 0)
+                j = _take_padded(ev_t.j, sel, 0)
+                s = _take_padded(ev_t.s, sel, 0)
+                r = _take_padded(ev_t.r, sel, 0)
+                d_ij = _take_padded(ev_t.deliver_ij, sel, False)
+                d_ji = _take_padded(ev_t.deliver_ji, sel, False)
+                st_ij = _take_padded(ev_t.stale_ij, sel, False)
+                st_ji = _take_padded(ev_t.stale_ji, sel, False)
+                overflow += jnp.maximum(jnp.sum(rel) - E, 0)
 
             # --- primal phase: compact local handshake endpoints, shared
             # exact quadratic step (core.sparse.batched_admm_primal)
-            f_i, f_j = fetch_q[i], fetch_q[j]
-            f_u = jnp.concatenate([f_i, f_j])                     # (2E,)
-            got = jnp.concatenate([d_ji, d_ij]) & (f_u < m)
-            usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
-            lu = _take_padded(f_u, usel, m)
-            lu_c = jnp.minimum(lu, m - 1)
-            if primal is None:
-                new_theta, theta_js = batched_admm_primal(
-                    w_blk[lu_c], live_blk[lu_c], Zo[lu_c], Zn[lu_c],
-                    Lo[lu_c], Ln[lu_c], D_blk[lu_c], mc_blk[lu_c],
-                    sx_blk[lu_c], mu, rho)
-            else:
-                xr = tuple(a[lu_c] for a in xym_blk)
-                new_theta, theta_js = primal.solve_batch(
-                    w_blk[lu_c], live_blk[lu_c], Zo[lu_c], Zn[lu_c],
-                    Lo[lu_c], Ln[lu_c], D_blk[lu_c], mc_blk[lu_c],
-                    sx_blk[lu_c], xr, theta[lu_c], mu, rho, None)
-            new_K = jnp.where(live_blk[lu_c][:, :, None], theta_js, K[lu_c])
-            rowp = jnp.where(lu < m, lu, m)
-            # scatter: idempotent — duplicate rows in lu derive identical
-            # values from the same round-start Z/L state
-            theta = theta.at[rowp].set(new_theta, mode="drop")
-            K = K.at[rowp].set(new_K, mode="drop")  # scatter: idempotent
-            overflow += jnp.maximum(jnp.sum(got) - U, 0)
+            with spans.phase("primal"):
+                f_i, f_j = fetch_q[i], fetch_q[j]
+                f_u = jnp.concatenate([f_i, f_j])                 # (2E,)
+                got = jnp.concatenate([d_ji, d_ij]) & (f_u < m)
+                usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
+                lu = _take_padded(f_u, usel, m)
+                lu_c = jnp.minimum(lu, m - 1)
+                if primal is None:
+                    new_theta, theta_js = batched_admm_primal(
+                        w_blk[lu_c], live_blk[lu_c], Zo[lu_c], Zn[lu_c],
+                        Lo[lu_c], Ln[lu_c], D_blk[lu_c], mc_blk[lu_c],
+                        sx_blk[lu_c], mu, rho)
+                else:
+                    xr = tuple(a[lu_c] for a in xym_blk)
+                    new_theta, theta_js = primal.solve_batch(
+                        w_blk[lu_c], live_blk[lu_c], Zo[lu_c], Zn[lu_c],
+                        Lo[lu_c], Ln[lu_c], D_blk[lu_c], mc_blk[lu_c],
+                        sx_blk[lu_c], xr, theta[lu_c], mu, rho, None)
+                new_K = jnp.where(live_blk[lu_c][:, :, None], theta_js,
+                                  K[lu_c])
+                rowp = jnp.where(lu < m, lu, m)
+                # scatter: idempotent — duplicate rows in lu derive
+                # identical values from the same round-start Z/L state
+                theta = theta.at[rowp].set(new_theta, mode="drop")
+                K = K.at[rowp].set(new_K, mode="drop")  # scatter: idempotent
+                overflow += jnp.maximum(jnp.sum(got) - U, 0)
 
             # --- publish + halo exchange (post-primal models, round-start
             # duals), then the edge phase reads payloads from ext
-            ext = publish(theta, K, Lo, Ln)
+            with spans.phase("exchange"):
+                ext = publish(theta, K, Lo, Ln)
 
             # --- edge phase: one half-step per delivered direction whose
             # receiver is local
-            own_s = jnp.concatenate([s, r])
-            oth_f = jnp.concatenate([f_j, f_i])
-            oth_s = jnp.concatenate([r, s])
-            stale = jnp.concatenate([st_ji, st_ij])[:, None, None]
-            pay = jnp.where(stale, ext_prev[oth_f], ext[oth_f])
-            ar = jnp.arange(oth_s.shape[0])
-            th_pay = pay[:, 0]
-            k_pay = pay[ar, 1 + oth_s]
-            lo_pay = pay[ar, 1 + k + oth_s]
-            ln_pay = pay[ar, 1 + 2 * k + oth_s]
-            own_c = jnp.minimum(f_u, m - 1)
-            z_own, z_nbr, lo_new, ln_new = admm_edge_halfstep(
-                theta[own_c], K[own_c, own_s], Lo[own_c, own_s],
-                Ln[own_c, own_s], th_pay, k_pay, lo_pay, ln_pay, rho)
-            rowe = jnp.where(got, f_u, m)
-            # scatter: unique targets — each event side writes its own
-            # (row, slot) cell; a slot belongs to one edge and each edge
-            # fires once per round
-            Zo = Zo.at[rowe, own_s].set(z_own, mode="drop")
-            Zn = Zn.at[rowe, own_s].set(z_nbr, mode="drop")  # scatter: unique targets
-            Lo = Lo.at[rowe, own_s].set(lo_new, mode="drop")  # scatter: unique targets
-            Ln = Ln.at[rowe, own_s].set(ln_new, mode="drop")  # scatter: unique targets
+            with spans.phase("edge"):
+                own_s = jnp.concatenate([s, r])
+                oth_f = jnp.concatenate([f_j, f_i])
+                oth_s = jnp.concatenate([r, s])
+                stale = jnp.concatenate([st_ji, st_ij])[:, None, None]
+                pay = jnp.where(stale, ext_prev[oth_f], ext[oth_f])
+                ar = jnp.arange(oth_s.shape[0])
+                th_pay = pay[:, 0]
+                k_pay = pay[ar, 1 + oth_s]
+                lo_pay = pay[ar, 1 + k + oth_s]
+                ln_pay = pay[ar, 1 + 2 * k + oth_s]
+                own_c = jnp.minimum(f_u, m - 1)
+                z_own, z_nbr, lo_new, ln_new = admm_edge_halfstep(
+                    theta[own_c], K[own_c, own_s], Lo[own_c, own_s],
+                    Ln[own_c, own_s], th_pay, k_pay, lo_pay, ln_pay, rho)
+                rowe = jnp.where(got, f_u, m)
+                # scatter: unique targets — each event side writes its own
+                # (row, slot) cell; a slot belongs to one edge and each
+                # edge fires once per round
+                Zo = Zo.at[rowe, own_s].set(z_own, mode="drop")
+                # scatter: unique targets
+                Zn = Zn.at[rowe, own_s].set(z_nbr, mode="drop")
+                # scatter: unique targets
+                Lo = Lo.at[rowe, own_s].set(lo_new, mode="drop")
+                # scatter: unique targets
+                Ln = Ln.at[rowe, own_s].set(ln_new, mode="drop")
             if tel:
-                stale, updates = tstate
-                stale = tmetrics.staleness_step(stale, got, f_u, m)
-                updates = updates + jnp.sum(got)
-                tstate = (stale, updates)
+                with spans.phase("record"):
+                    stale, updates = tstate
+                    stale = tmetrics.staleness_step(stale, got, f_u, m)
+                    updates = updates + jnp.sum(got)
+                    tstate = (stale, updates)
             return (theta, K, Zo, Zn, Lo, Ln, ext, overflow, *tstate), None
 
         def outer(carry, ev_blk):
             carry, _ = jax.lax.scan(round_fn, carry, ev_blk)
             if tel:
-                if primal is not None and primal.needs_data:
-                    loss_vec = primal.batch_local_loss(carry[0], *xym_blk)
-                    obj = tmetrics.cl_local_objective_from_loss(
-                        carry[0], carry[1], w_blk, live_blk, D_blk,
-                        loss_vec, mu)
-                else:
-                    (sxx_blk,) = tel_blks
-                    obj = tmetrics.cl_local_objective(
-                        carry[0], carry[1], w_blk, live_blk, D_blk, mc_blk,
-                        sx_blk, sxx_blk, mu)
-                stale, updates = carry[8:]
-                return carry, (carry[0], obj, stale, updates[None])
+                with spans.phase("record"):
+                    if primal is not None and primal.needs_data:
+                        loss_vec = primal.batch_local_loss(carry[0],
+                                                           *xym_blk)
+                        obj = tmetrics.cl_local_objective_from_loss(
+                            carry[0], carry[1], w_blk, live_blk, D_blk,
+                            loss_vec, mu)
+                    else:
+                        (sxx_blk,) = tel_blks
+                        obj = tmetrics.cl_local_objective(
+                            carry[0], carry[1], w_blk, live_blk, D_blk,
+                            mc_blk, sx_blk, sxx_blk, mu)
+                    stale, updates = carry[8:]
+                    return carry, (carry[0], obj, stale, updates[None])
             return carry, carry[0]
 
-        st0 = warm_admm_from_copies(theta0_blk, K0_blk)
-        ext0 = publish(st0.theta, st0.K, st0.L_own, st0.L_nbr)  # warm halo
-        carry0 = (st0.theta, st0.K, st0.Z_own, st0.Z_nbr, st0.L_own,
-                  st0.L_nbr, ext0, jnp.int32(0))
-        if tel:
-            carry0 = carry0 + (jnp.zeros((m,), jnp.int32), jnp.int32(0))
+        with spans.phase("init"):
+            st0 = warm_admm_from_copies(theta0_blk, K0_blk)
+        with spans.phase("exchange"):
+            ext0 = publish(st0.theta, st0.K, st0.L_own, st0.L_nbr)  # warm
+        with spans.phase("init"):
+            carry0 = (st0.theta, st0.K, st0.Z_own, st0.Z_nbr, st0.L_own,
+                      st0.L_nbr, ext0, jnp.int32(0))
+            if tel:
+                carry0 = carry0 + (jnp.zeros((m,), jnp.int32), jnp.int32(0))
         carry, hist = jax.lax.scan(outer, carry0, ev)
         theta, overflow = carry[0], carry[7]
         if tel:
@@ -841,86 +886,98 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
     does — the agents' padded local datasets are row-sharded alongside
     the ADMM state.
     """
-    mesh, P_, assignment, part = _sharded_setup(
-        topo, n_shards, mesh, assignment, partition_seed)
-
-    tabs = topo.tables
     record_every, n_rec = record_chunks(rounds, record_every)
     total_rounds = n_rec * record_every
-
-    if stream is None:
-        stream = precompute_event_stream(
-            topo.device_tables(), jnp.asarray(topo.partition_halves()),
-            conditions, batch, seed, total_rounds)
-    else:
-        if stream.i.shape[0] != total_rounds:
-            raise ValueError(
-                f"stream covers {stream.i.shape[0]} rounds but the clamped "
-                f"horizon is {total_rounds}")
-        batch = int(stream.i.shape[1])
-
+    if stream is not None and stream.i.shape[0] != total_rounds:
+        raise ValueError(
+            f"stream covers {stream.i.shape[0]} rounds but the clamped "
+            f"horizon is {total_rounds}")
     if theta_sol is None:
         raise ValueError("need theta_sol (warm start)")
-    # warm start (paper §4.2): the neighbour copies are gathered on the
-    # host (they cross shards); each shard derives the rest in-jit
-    theta0 = np.asarray(theta_sol, np.float32).reshape(topo.n, -1)
-    # the local-data reductions use the same jnp expressions as the
-    # single-device engine (numpy's pairwise summation rounds differently,
-    # which would break the bit-for-bit parity)
-    mask = jnp.asarray(data.mask, jnp.float32)
-    x = jnp.asarray(data.x, jnp.float32)
-    m_counts = np.asarray(jnp.sum(mask, axis=1))
-    sx = np.asarray(jnp.sum(x * mask[:, :, None], axis=1))
-    needs_data = primal is not None and primal.needs_data
-    xym = ()
-    if needs_data:
-        xym = tuple(_on_shards(mesh, part.shard_rows(np.asarray(a)))
-                    for a in (x, jnp.asarray(data.y, jnp.float32), mask))
-    sharded = dict(
-        theta0=part.shard_rows(theta0),
-        K0=part.shard_rows(theta0[tabs.nbr_idx]),
-        nbr_w=part.shard_rows(tabs.nbr_w),
-        deg_count=part.shard_rows(tabs.deg_count),
-        D=part.shard_rows(tabs.deg_w.astype(np.float32)),
-        m_counts=part.shard_rows(m_counts),
-        sx=part.shard_rows(sx))
-    E, U = _local_capacities(batch, P_, local_batch)
+    with spans.host("prepare") as span:
+        mesh, P_, assignment, part = _sharded_setup(
+            topo, n_shards, mesh, assignment, partition_seed)
 
-    tel = telemetry_on(telemetry)
-    tel_args = ()
-    if tel and not needs_data:
-        sxx = np.asarray(jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1))
-        tel_args = (_on_shards(mesh, part.shard_rows(sxx)),)
-    codec = resolve_halo_codec(halo_codec)
-    outs = _sharded_cl_scan(
-        mesh, stream,
-        **{k_: _on_shards(mesh, v) for k_, v in sharded.items()},
-        **_partition_args(mesh, part), tel_args=tel_args,
-        xym=xym,
-        mu=mu, rho=rho, k=topo.k_max, m=part.shard_size, H=part.halo_size,
-        E=E, U=U, n_rec=n_rec, record_every=record_every,
-        exchange=exchange, codec=codec, tel=tel, primal=primal)
-    frames = None
-    if tel:
-        hist, theta, overflow, obj_h, stale_h, upd_h = outs
-        p_dim = int(theta0.shape[1])
-        frames = _sharded_frames(
-            part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
-            overflow,
-            payload_row_bytes=codec.row_nbytes((1 + 3 * topo.k_max, p_dim)))
-    else:
-        hist, theta, overflow = outs
+        tabs = topo.tables
+        up = 0
+        if stream is None:
+            halves = topo.partition_halves()
+            up += spans.upload_bytes(tabs, halves)
+            stream = precompute_event_stream(
+                topo.device_tables(), jnp.asarray(halves), conditions,
+                batch, seed, total_rounds)
+        else:
+            batch = int(stream.i.shape[1])
 
-    delivered, dropped, invalid = stream_totals(stream)
-    active_hist = np.asarray(stream.active_frac).reshape(
-        n_rec, record_every)[:, -1]
-    return ShardedSimTrace(
-        theta_hist=part.unshard_rows(np.asarray(hist)),
-        active_hist=active_hist, delivered=delivered, dropped=dropped,
-        rounds=total_rounds, events=total_rounds * batch, invalid=invalid,
-        telemetry=frames, n_shards=P_, edge_cut=part.edge_cut,
-        halo_size=part.halo_size, local_batch=U,
-        overflow=int(np.asarray(overflow).sum()))
+        # warm start (paper §4.2): the neighbour copies are gathered on
+        # the host (they cross shards); each shard derives the rest in-jit
+        theta0 = np.asarray(theta_sol, np.float32).reshape(topo.n, -1)
+        # the local-data reductions use the same jnp expressions as the
+        # single-device engine (numpy's pairwise summation rounds
+        # differently, which would break the bit-for-bit parity)
+        up += spans.upload_bytes(data.mask, data.x)
+        mask = jnp.asarray(data.mask, jnp.float32)
+        x = jnp.asarray(data.x, jnp.float32)
+        m_counts = np.asarray(jnp.sum(mask, axis=1))
+        sx = np.asarray(jnp.sum(x * mask[:, :, None], axis=1))
+        needs_data = primal is not None and primal.needs_data
+        xym = ()
+        if needs_data:
+            xym_rows = [part.shard_rows(np.asarray(a)) for a in
+                        (x, jnp.asarray(data.y, jnp.float32), mask)]
+            up += spans.upload_bytes(data.y, xym_rows)
+            xym = tuple(_on_shards(mesh, a) for a in xym_rows)
+        sharded = dict(
+            theta0=part.shard_rows(theta0),
+            K0=part.shard_rows(theta0[tabs.nbr_idx]),
+            nbr_w=part.shard_rows(tabs.nbr_w),
+            deg_count=part.shard_rows(tabs.deg_count),
+            D=part.shard_rows(tabs.deg_w.astype(np.float32)),
+            m_counts=part.shard_rows(m_counts),
+            sx=part.shard_rows(sx))
+        E, U = _local_capacities(batch, P_, local_batch)
+
+        tel = telemetry_on(telemetry)
+        tel_args = ()
+        if tel and not needs_data:
+            sxx = part.shard_rows(np.asarray(
+                jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1)))
+            up += spans.upload_bytes(sxx)
+            tel_args = (_on_shards(mesh, sxx),)
+        codec = resolve_halo_codec(halo_codec)
+        args, up_rows = _upload(mesh, part, sharded)
+        span.set_metadata(bytes=up + up_rows)
+    with spans.host("scan"):
+        outs = _sharded_cl_scan(
+            mesh, stream, **args, tel_args=tel_args, xym=xym,
+            mu=mu, rho=rho, k=topo.k_max, m=part.shard_size,
+            H=part.halo_size, E=E, U=U, n_rec=n_rec,
+            record_every=record_every, exchange=exchange, codec=codec,
+            tel=tel, primal=primal)
+    delivered, dropped, invalid, active_hist = _fetch_stream_totals(
+        stream, n_rec, record_every)
+    with spans.host("wait"):
+        jax.block_until_ready(outs)
+    # the final theta (outs[1]) stays on the device
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            outs[:1], outs[2:], (stream.cut, stream.dead) if tel else None)):
+        frames = None
+        if tel:
+            hist, theta, overflow, obj_h, stale_h, upd_h = outs
+            p_dim = int(theta0.shape[1])
+            frames = _sharded_frames(
+                part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
+                overflow, payload_row_bytes=codec.row_nbytes(
+                    (1 + 3 * topo.k_max, p_dim)))
+        else:
+            hist, theta, overflow = outs
+        return ShardedSimTrace(
+            theta_hist=part.unshard_rows(np.asarray(hist)),
+            active_hist=active_hist, delivered=delivered, dropped=dropped,
+            rounds=total_rounds, events=total_rounds * batch,
+            invalid=invalid, telemetry=frames, n_shards=P_,
+            edge_cut=part.edge_cut, halo_size=part.halo_size, local_batch=U,
+            overflow=int(np.asarray(overflow).sum()))
 
 
 # ---------------------------------------------------------------------------
@@ -1006,55 +1063,61 @@ def _sharded_joint_scan(mesh, stream, ts, theta0, K0, theta_prev0, w0,
                 *tstate = carry
             ev_t, t = inp
             theta_in = theta
-            ext = exchange_halo(theta)
+            with spans.phase("exchange"):
+                ext = exchange_halo(theta)
 
             # --- compact to the events touching this shard (O(E) ~ 2B/P)
-            rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
-            sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
-            i = _take_padded(ev_t.i, sel, 0)
-            j = _take_padded(ev_t.j, sel, 0)
-            s = _take_padded(ev_t.s, sel, 0)
-            r = _take_padded(ev_t.r, sel, 0)
-            d_ij = _take_padded(ev_t.deliver_ij, sel, False)
-            d_ji = _take_padded(ev_t.deliver_ji, sel, False)
-            st_ij = _take_padded(ev_t.stale_ij, sel, False)
-            st_ji = _take_padded(ev_t.stale_ji, sel, False)
-            overflow += jnp.maximum(jnp.sum(rel) - E, 0)
+            with spans.phase("draw"):
+                rel = (fetch_q[ev_t.i] < m) | (fetch_q[ev_t.j] < m)
+                sel = jnp.nonzero(rel, size=E, fill_value=batch)[0]
+                i = _take_padded(ev_t.i, sel, 0)
+                j = _take_padded(ev_t.j, sel, 0)
+                s = _take_padded(ev_t.s, sel, 0)
+                r = _take_padded(ev_t.r, sel, 0)
+                d_ij = _take_padded(ev_t.deliver_ij, sel, False)
+                d_ji = _take_padded(ev_t.deliver_ji, sel, False)
+                st_ij = _take_padded(ev_t.stale_ij, sel, False)
+                st_ji = _take_padded(ev_t.stale_ji, sel, False)
+                overflow += jnp.maximum(jnp.sum(rel) - E, 0)
 
             # --- communication (receiver-side pruning voids a delivery)
-            f_i, f_j = fetch_q[i], fetch_q[j]
-            if prune:
-                lv_j = live[jnp.minimum(f_j, m - 1), r] & (f_j < m)
-                lv_i = live[jnp.minimum(f_i, m - 1), s] & (f_i < m)
-                ok_ij = d_ij & lv_j
-                ok_ji = d_ji & lv_i
-                suppressed = suppressed \
-                    + jnp.sum(d_ij & (f_j < m) & ~lv_j) \
-                    + jnp.sum(d_ji & (f_i < m) & ~lv_i)
-            else:
-                ok_ij, ok_ji = d_ij, d_ji
-            msg_i = jnp.where(st_ij[:, None], ext_prev[f_i], ext[f_i])
-            msg_j = jnp.where(st_ji[:, None], ext_prev[f_j], ext[f_j])
-            row_j = jnp.where(ok_ij & (f_j < m), f_j, m)
-            row_i = jnp.where(ok_ji & (f_i < m), f_i, m)
-            # scatter: last-write-wins — a repeated edge in one batch lands
-            # the batch-order winner (mirrors the dense scenario engine)
-            K = K.at[row_j, r].set(msg_i, mode="drop")
-            K = K.at[row_i, s].set(msg_j, mode="drop")  # scatter: last-write-wins
+            with spans.phase("comm"):
+                f_i, f_j = fetch_q[i], fetch_q[j]
+                if prune:
+                    lv_j = live[jnp.minimum(f_j, m - 1), r] & (f_j < m)
+                    lv_i = live[jnp.minimum(f_i, m - 1), s] & (f_i < m)
+                    ok_ij = d_ij & lv_j
+                    ok_ji = d_ji & lv_i
+                    suppressed = suppressed \
+                        + jnp.sum(d_ij & (f_j < m) & ~lv_j) \
+                        + jnp.sum(d_ji & (f_i < m) & ~lv_i)
+                else:
+                    ok_ij, ok_ji = d_ij, d_ji
+                msg_i = jnp.where(st_ij[:, None], ext_prev[f_i], ext[f_i])
+                msg_j = jnp.where(st_ji[:, None], ext_prev[f_j], ext[f_j])
+                row_j = jnp.where(ok_ij & (f_j < m), f_j, m)
+                row_i = jnp.where(ok_ji & (f_i < m), f_i, m)
+                # scatter: last-write-wins — a repeated edge in one batch
+                # lands the batch-order winner (mirrors the dense scenario
+                # engine)
+                K = K.at[row_j, r].set(msg_i, mode="drop")
+                K = K.at[row_i, s].set(msg_j, mode="drop")  # scatter: last-write-wins
 
             # --- update: compact local endpoints, shared Eq. (6) step
             # under the current learned weights
-            f_u = jnp.concatenate([f_i, f_j])
-            got = jnp.concatenate([ok_ji, ok_ij]) & (f_u < m)
-            usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
-            lu = _take_padded(f_u, usel, m)
-            lu_c = jnp.minimum(lu, m - 1)
-            new = batched_model_update(w[lu_c], K[lu_c], c_blk[lu_c],
-                                       sol_blk[lu_c], alpha, backend)
-            # scatter: idempotent — duplicate rows in lu recompute the same
-            # value from the same post-communication K
-            theta = theta.at[jnp.where(lu < m, lu, m)].set(new, mode="drop")
-            overflow += jnp.maximum(jnp.sum(got) - U, 0)
+            with spans.phase("update"):
+                f_u = jnp.concatenate([f_i, f_j])
+                got = jnp.concatenate([ok_ji, ok_ij]) & (f_u < m)
+                usel = jnp.nonzero(got, size=U, fill_value=2 * E)[0]
+                lu = _take_padded(f_u, usel, m)
+                lu_c = jnp.minimum(lu, m - 1)
+                new = batched_model_update(w[lu_c], K[lu_c], c_blk[lu_c],
+                                           sol_blk[lu_c], alpha, backend)
+                # scatter: idempotent — duplicate rows in lu recompute the
+                # same value from the same post-communication K
+                theta = theta.at[jnp.where(lu < m, lu, m)].set(new,
+                                                               mode="drop")
+                overflow += jnp.maximum(jnp.sum(got) - U, 0)
 
             # --- graph step over this shard's local rows (compiled out at
             # rate 0; identical row arithmetic to the single-device engine)
@@ -1066,32 +1129,36 @@ def _sharded_joint_scan(mesh, stream, ts, theta0, K0, theta_prev0, w0,
                         return prune_rows(w2, live, prune_eps)
                     return w2, live
 
-                w, live = jax.lax.cond(
-                    (t + 1) % graph_every == 0, do_graph,
-                    lambda w, live: (w, live), w, live)
+                with spans.phase("graph"):
+                    w, live = jax.lax.cond(
+                        (t + 1) % graph_every == 0, do_graph,
+                        lambda w, live: (w, live), w, live)
 
             if tel:
-                stale, updates = tstate
-                stale = tmetrics.staleness_step(stale, got, f_u, m)
-                updates = updates + jnp.sum(got)
-                tstate = (stale, updates)
+                with spans.phase("record"):
+                    stale, updates = tstate
+                    stale = tmetrics.staleness_step(stale, got, f_u, m)
+                    updates = updates + jnp.sum(got)
+                    tstate = (stale, updates)
             return (theta, K, theta_in, w, live, ext, suppressed,
                     overflow, *tstate), None
 
         def outer(carry, inp):
             carry, _ = jax.lax.scan(round_fn, carry, inp)
             theta, _, _, w, live = carry[:5]
-            edges = jnp.sum(live & (w > 0))[None]
-            if tel:
-                obj = tmetrics.mp_local_objective(
-                    theta, carry[1], jnp.where(live, w, 0.0), c_blk,
-                    sol_blk, alpha)
-                stale, updates = carry[8:]
-                return carry, (theta, edges, obj, stale, updates[None],
-                               carry[6][None])
+            with spans.phase("record"):
+                edges = jnp.sum(live & (w > 0))[None]
+                if tel:
+                    obj = tmetrics.mp_local_objective(
+                        theta, carry[1], jnp.where(live, w, 0.0), c_blk,
+                        sol_blk, alpha)
+                    stale, updates = carry[8:]
+                    return carry, (theta, edges, obj, stale, updates[None],
+                                   carry[6][None])
             return carry, (theta, edges)
 
-        ext_prev0 = exchange_halo(thp0_blk)
+        with spans.phase("exchange"):
+            ext_prev0 = exchange_halo(thp0_blk)
         carry0 = (theta0_blk, K0_blk, thp0_blk, w0_blk, live0_blk, ext_prev0,
                   jnp.int32(0), jnp.int32(0))
         if tel:
@@ -1159,133 +1226,157 @@ def run_joint_scenario_sharded(topo: SparseTopology, theta_sol, c,
     only the exchange volume shrinks.  ``rounds`` is first floored by the
     shared recording policy; segment boundaries land on record chunks.
     """
-    mesh, P_, assignment, part = _sharded_setup(
-        topo, n_shards, mesh, assignment, partition_seed)
-    owner = np.asarray(assignment, np.int32)
-    full_cut = part.edge_cut
+    with spans.host("prepare") as span:
+        mesh, P_, assignment, part = _sharded_setup(
+            topo, n_shards, mesh, assignment, partition_seed)
+        owner = np.asarray(assignment, np.int32)
+        full_cut = part.edge_cut
 
-    tabs = topo.tables
-    n = topo.n
-    theta_sol = np.asarray(theta_sol, np.float32).reshape(n, -1)
-    c = np.asarray(c, np.float32)
-    record_every, n_rec = record_chunks(rounds, record_every)
-    total_rounds = n_rec * record_every
+        tabs = topo.tables
+        n = topo.n
+        theta_sol = np.asarray(theta_sol, np.float32).reshape(n, -1)
+        c = np.asarray(c, np.float32)
+        record_every, n_rec = record_chunks(rounds, record_every)
+        total_rounds = n_rec * record_every
 
-    if stream is None:
-        stream = precompute_event_stream(
-            topo.device_tables(), jnp.asarray(topo.partition_halves()),
-            conditions, batch, seed, total_rounds)
-    else:
-        if stream.i.shape[0] != total_rounds:
-            raise ValueError(
-                f"stream covers {stream.i.shape[0]} rounds but the clamped "
-                f"horizon is {total_rounds}")
-        batch = int(stream.i.shape[1])
+        up = 0
+        if stream is None:
+            halves = topo.partition_halves()
+            up += spans.upload_bytes(tabs, halves)
+            stream = precompute_event_stream(
+                topo.device_tables(), jnp.asarray(halves), conditions,
+                batch, seed, total_rounds)
+        else:
+            if stream.i.shape[0] != total_rounds:
+                raise ValueError(
+                    f"stream covers {stream.i.shape[0]} rounds but the "
+                    f"clamped horizon is {total_rounds}")
+            batch = int(stream.i.shape[1])
 
-    K0 = theta_sol[tabs.nbr_idx]                     # warm start (§3.2)
-    live0 = np.asarray(live_slots(tabs.deg_count, tabs.k_max))
-    theta = _on_shards(mesh, part.shard_rows(theta_sol))
-    K = _on_shards(mesh, part.shard_rows(K0))
-    theta_prev = theta
-    w = _on_shards(mesh, part.shard_rows(tabs.nbr_p))
-    live = _on_shards(mesh, part.shard_rows(live0))
-    c_sh = _on_shards(mesh, part.shard_rows(c))
-    sol_sh = _on_shards(mesh, part.shard_rows(theta_sol))
-    E, U = _local_capacities(batch, P_, local_batch)
+        K0 = theta_sol[tabs.nbr_idx]                 # warm start (§3.2)
+        live0 = np.asarray(live_slots(tabs.deg_count, tabs.k_max))
+        rows = dict(theta=part.shard_rows(theta_sol), K=part.shard_rows(K0),
+                    w=part.shard_rows(tabs.nbr_p),
+                    live=part.shard_rows(live0), c=part.shard_rows(c),
+                    sol=part.shard_rows(theta_sol))
+        up += spans.upload_bytes(rows)
+        theta = _on_shards(mesh, rows["theta"])
+        K = _on_shards(mesh, rows["K"])
+        theta_prev = theta
+        w = _on_shards(mesh, rows["w"])
+        live = _on_shards(mesh, rows["live"])
+        c_sh = _on_shards(mesh, rows["c"])
+        sol_sh = _on_shards(mesh, rows["sol"])
+        E, U = _local_capacities(batch, P_, local_batch)
 
-    # segment schedule (record chunks per jitted call)
-    can_recompact = (eta_graph > 0.0 and prune_eps is not None
-                     and recompact_every is not None)
-    if can_recompact:
-        # repro-lint: disable=RPL007  n_rec already record_chunks-normalized
-        seg = recompact_every // record_every
-        seg_rec = max(1, min(n_rec, seg))
-    else:
-        seg_rec = n_rec
-    cross_at_compact = _live_cross_edges(tabs, owner, live0)
+        # segment schedule (record chunks per jitted call)
+        can_recompact = (eta_graph > 0.0 and prune_eps is not None
+                         and recompact_every is not None)
+        if can_recompact:
+            # repro-lint: disable=RPL007  n_rec already record_chunks-normalized
+            seg = recompact_every // record_every
+            seg_rec = max(1, min(n_rec, seg))
+        else:
+            seg_rec = n_rec
+        cross_at_compact = _live_cross_edges(tabs, owner, live0)
 
-    tel = telemetry_on(telemetry)
-    codec = resolve_halo_codec(halo_codec)
-    p_dim = theta_sol.shape[1]
-    stale = jnp.zeros((P_ * part.shard_size,), jnp.int32) if tel else None
-    tel_obj, tel_stale, tel_upd, tel_sup, tel_halo = [], [], [], [], []
-    upd_off = sup_off = halo_off = 0
-    ovf_shards = np.zeros(P_, np.int64)
+        tel = telemetry_on(telemetry)
+        codec = resolve_halo_codec(halo_codec)
+        p_dim = theta_sol.shape[1]
+        stale = jnp.zeros((P_ * part.shard_size,), jnp.int32) if tel \
+            else None
+        tel_obj, tel_stale, tel_upd, tel_sup, tel_halo = [], [], [], [], []
+        upd_off = sup_off = halo_off = 0
+        ovf_shards = np.zeros(P_, np.int64)
+        span.set_metadata(bytes=up)
 
     hists, live_hists = [], []
     suppressed = overflow = recompactions = 0
     done = 0
     while done < n_rec:
         seg = min(seg_rec, n_rec - done)
-        ev_seg = _slice_stream(stream, done * record_every,
-                               (done + seg) * record_every)
-        ts_seg = jnp.arange(done * record_every,
-                            (done + seg) * record_every,
-                            dtype=jnp.int32).reshape(seg, record_every)
-        (hist, live_hist, theta, K, theta_prev, w, live, sup, ovf,
-         *tel_out) = _sharded_joint_scan(
+        with spans.host("prepare") as span:
+            span.set_metadata(bytes=spans.upload_bytes(
+                part.fetch, part.bnd_pos, part.halo_src_shard,
+                part.halo_src_pos))
+            ev_seg = _slice_stream(stream, done * record_every,
+                                   (done + seg) * record_every)
+            ts_seg = jnp.arange(done * record_every,
+                                (done + seg) * record_every,
+                                dtype=jnp.int32).reshape(seg, record_every)
+            pargs = _partition_args(mesh, part).values()
+        with spans.host("scan"):
+            outs = _sharded_joint_scan(
                 mesh, ev_seg, ts_seg, theta, K, theta_prev, w, live,
-                c_sh, sol_sh, *_partition_args(mesh, part).values(),
-                (stale,) if tel else (), alpha=alpha,
+                c_sh, sol_sh, *pargs, (stale,) if tel else (), alpha=alpha,
                 eta_graph=eta_graph, lam=lam, graph_every=graph_every,
                 prune_eps=prune_eps, m=part.shard_size, H=part.halo_size,
                 E=E, U=U, n_rec=seg, record_every=record_every,
                 exchange=exchange, codec=codec, backend=backend, tel=tel)
-        hists.append(np.asarray(hist))
-        live_hists.append(np.asarray(live_hist).sum(axis=1))
-        suppressed += int(np.asarray(sup).sum())
-        overflow += int(np.asarray(ovf).sum())
-        if tel:
-            obj_h, stale_h, upd_h, sup_h, stale = tel_out
-            tel_obj.append(np.asarray(obj_h))
-            tel_stale.append(np.asarray(stale_h))
-            seg_upd = np.asarray(upd_h, np.int64).sum(axis=1)
-            tel_upd.append(upd_off + seg_upd)
-            upd_off = int(tel_upd[-1][-1])
-            seg_sup = np.asarray(sup_h, np.int64).sum(axis=1)
-            tel_sup.append(sup_off + seg_sup)
-            sup_off = int(tel_sup[-1][-1])
-            # halo payload of *this* segment's layout (re-compaction
-            # shrinks the boundary between segments)
-            per_round = halo_payload_bytes(
-                P_, part.boundary_size, codec.row_nbytes((p_dim,)),
-                part.halo_size)
-            seg_rounds = (np.arange(seg, dtype=np.int64) + 1) * record_every
-            tel_halo.append(halo_off + seg_rounds * per_round)
-            halo_off = int(tel_halo[-1][-1])
-            ovf_shards += np.asarray(ovf, np.int64)
+        with spans.host("wait"):
+            jax.block_until_ready(outs)
+        (hist, live_hist, theta, K, theta_prev, w, live, sup, ovf,
+         *tel_out) = outs
+        with spans.host("fetch", bytes=spans.fetch_bytes(
+                hist, live_hist, sup, ovf, tel_out[:4])):
+            hists.append(np.asarray(hist))
+            live_hists.append(np.asarray(live_hist).sum(axis=1))
+            suppressed += int(np.asarray(sup).sum())
+            overflow += int(np.asarray(ovf).sum())
+            if tel:
+                obj_h, stale_h, upd_h, sup_h, stale = tel_out
+                tel_obj.append(np.asarray(obj_h))
+                tel_stale.append(np.asarray(stale_h))
+                seg_upd = np.asarray(upd_h, np.int64).sum(axis=1)
+                tel_upd.append(upd_off + seg_upd)
+                upd_off = int(tel_upd[-1][-1])
+                seg_sup = np.asarray(sup_h, np.int64).sum(axis=1)
+                tel_sup.append(sup_off + seg_sup)
+                sup_off = int(tel_sup[-1][-1])
+                # halo payload of *this* segment's layout (re-compaction
+                # shrinks the boundary between segments)
+                per_round = halo_payload_bytes(
+                    P_, part.boundary_size, codec.row_nbytes((p_dim,)),
+                    part.halo_size)
+                seg_rounds = (np.arange(seg, dtype=np.int64) + 1) \
+                    * record_every
+                tel_halo.append(halo_off + seg_rounds * per_round)
+                halo_off = int(tel_halo[-1][-1])
+                ovf_shards += np.asarray(ovf, np.int64)
         done += seg
         if done < n_rec and can_recompact and cross_at_compact > 0:
-            live_host = part.unshard_rows(np.asarray(live))
-            cur_cross = _live_cross_edges(tabs, owner, live_host)
-            if cur_cross <= (1.0 - recompact_frac) * cross_at_compact:
-                part = GraphPartition.build(topo, assignment, P_,
-                                            live=live_host)
-                cross_at_compact = cur_cross
-                recompactions += 1
+            with spans.host("fetch", bytes=spans.fetch_bytes(live)):
+                live_host = part.unshard_rows(np.asarray(live))
+            with spans.host("prepare"):
+                cur_cross = _live_cross_edges(tabs, owner, live_host)
+                if cur_cross <= (1.0 - recompact_frac) * cross_at_compact:
+                    part = GraphPartition.build(topo, assignment, P_,
+                                                live=live_host)
+                    cross_at_compact = cur_cross
+                    recompactions += 1
 
-    frames = None
-    if tel:
-        frames = TelemetryFrames(
-            rounds=(np.arange(n_rec, dtype=np.int64) + 1) * record_every,
-            objective=np.concatenate(tel_obj)[:, part.perm_slot],
-            staleness=np.concatenate(tel_stale)[:, part.perm_slot],
-            updates=np.concatenate(tel_upd),
-            halo_bytes=np.concatenate(tel_halo),
-            overflow_per_shard=ovf_shards,
-            suppressed=np.concatenate(tel_sup),
-            **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
-
-    delivered, dropped, invalid = stream_totals(stream)
-    active_hist = np.asarray(stream.active_frac).reshape(
-        n_rec, record_every)[:, -1]
-    return JointShardedTrace(
-        theta_hist=part.unshard_rows(np.concatenate(hists, axis=0)),
-        active_hist=active_hist, delivered=delivered, dropped=dropped,
-        rounds=total_rounds, events=total_rounds * batch, invalid=invalid,
-        telemetry=frames, n_shards=P_, edge_cut=full_cut,
-        halo_size=part.halo_size, local_batch=U, overflow=overflow,
-        final_w=part.unshard_rows(np.asarray(w)),
-        final_live=part.unshard_rows(np.asarray(live)),
-        live_edges_hist=np.concatenate(live_hists),
-        suppressed=suppressed, recompactions=recompactions)
+    delivered, dropped, invalid, active_hist = _fetch_stream_totals(
+        stream, n_rec, record_every)
+    with spans.host("fetch", bytes=spans.fetch_bytes(
+            w, live, (stream.cut, stream.dead) if tel else None)):
+        frames = None
+        if tel:
+            frames = TelemetryFrames(
+                rounds=(np.arange(n_rec, dtype=np.int64) + 1) * record_every,
+                objective=np.concatenate(tel_obj)[:, part.perm_slot],
+                staleness=np.concatenate(tel_stale)[:, part.perm_slot],
+                updates=np.concatenate(tel_upd),
+                halo_bytes=np.concatenate(tel_halo),
+                overflow_per_shard=ovf_shards,
+                suppressed=np.concatenate(tel_sup),
+                **tmetrics.stream_chunk_totals(stream, n_rec, record_every))
+        return JointShardedTrace(
+            theta_hist=part.unshard_rows(np.concatenate(hists, axis=0)),
+            active_hist=active_hist, delivered=delivered, dropped=dropped,
+            rounds=total_rounds, events=total_rounds * batch,
+            invalid=invalid, telemetry=frames, n_shards=P_,
+            edge_cut=full_cut, halo_size=part.halo_size, local_batch=U,
+            overflow=overflow, final_w=part.unshard_rows(np.asarray(w)),
+            final_live=part.unshard_rows(np.asarray(live)),
+            live_edges_hist=np.concatenate(live_hists),
+            suppressed=suppressed, recompactions=recompactions)

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
+
 
 @dataclasses.dataclass(frozen=True)
 class NetworkConditions:
@@ -304,8 +306,11 @@ def _draw_stream(tabs, part_half, rates, keys, *,
         frac = jnp.mean(active.astype(jnp.float32))
         return active, (ev, frac)
 
-    ts = jnp.arange(rounds, dtype=jnp.int32)
-    _, (evs, fracs) = jax.lax.scan(step, jnp.ones((n,), bool), (ts, keys))
+    # the whole executable is the draw phase of the rounds that replay it
+    with spans.phase("draw"):
+        ts = jnp.arange(rounds, dtype=jnp.int32)
+        _, (evs, fracs) = jax.lax.scan(step, jnp.ones((n,), bool),
+                                       (ts, keys))
     return EventStream(*evs, fracs)
 
 

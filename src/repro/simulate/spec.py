@@ -30,6 +30,7 @@ from repro.telemetry.metrics import (stream_dirty_chunks,
 
 from . import engines as _engines
 from . import partition as _partition
+from . import spans
 from .scheduler import (EventStream, NetworkConditions, ServeStream,
                         precompute_event_stream, serve_chunk_requests)
 
@@ -132,7 +133,28 @@ def run_scenario(spec: ScenarioSpec):
     ``ServeReport`` as ``trace.serve`` (plus the cumulative serve
     counters on ``trace.telemetry`` when telemetry is enabled).  Returns
     the engine's trace type unchanged otherwise.
+
+    The call runs inside the profiler span ``repro/scenario``, whose args
+    identify it; the engines' host steps nest inside it
+    (``repro.simulate.spans``).
     """
+    with spans.scenario(
+        algo=spec.algo,
+        n=spec.topology.n,
+        rounds=spec.rounds,
+        batch=spec.batch,
+        seed=spec.seed,
+        sharded=spec.sharded,
+    ):
+        trace = _run_engine(spec)
+        if spec.serve is not None:
+            with spans.host("serve", requests=spec.serve.n_requests):
+                trace = _drive_serve(spec, trace)
+    return trace
+
+
+def _run_engine(spec: ScenarioSpec):
+    """The algo's engine (single-device or partitioned) on the spec."""
     common = dict(conditions=spec.conditions, rounds=spec.rounds,
                   batch=spec.batch, seed=spec.seed,
                   record_every=spec.record_every, telemetry=spec.telemetry)
@@ -188,8 +210,6 @@ def run_scenario(spec: ScenarioSpec):
             trace = _engines.run_joint_scenario(
                 spec.topology, spec.theta_sol, spec.c, spec.alpha,
                 **common, **joint_kw)
-    if spec.serve is not None:
-        trace = _drive_serve(spec, trace)
     return trace
 
 

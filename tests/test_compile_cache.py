@@ -51,3 +51,36 @@ def test_env_dir_is_used_and_written(tmp_path):
 def test_default_is_fixed_dir_in_checkout():
     chosen, config_dir = _probe(None, False)
     assert chosen == config_dir == str(ROOT / ".jax_cache")
+
+
+_SCOPED = """
+import os, jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+d = enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+def make(scope):
+    def step(x):
+        with jax.named_scope(scope):
+            return jnp.sin(x) * 3.0
+    return step
+counts = []
+for scope in ("a", "b"):
+    jax.jit(make(scope))(jnp.ones(8)).block_until_ready()
+    counts.append(len(os.listdir(d)))
+print(*counts)
+"""
+
+
+def test_programs_differing_only_in_scopes_are_cached_apart(tmp_path):
+    """The same ops under another ``named_scope`` are a cache miss, so a
+    program never runs with the op names of an older one (a profile then
+    names its ops as the code that ran)."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run([sys.executable, "-c", _SCOPED], env=env, cwd=ROOT,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    first, second = map(int, out.stdout.split())
+    assert 0 < first < second
