@@ -38,7 +38,7 @@ SEED = 0
 # its last two dims, so k_max = 17-18 pads to 24 and p = 64 to 128: 2.8x
 # its packed size.  The v5e compiler's memory analysis (tests/
 # test_chip_compile.py) then bounds one chip at about n = 500_000 for MP
-# (~11.6 GiB per-op, ~13.3 GiB fused) and n = 100_000 for CL-ADMM
+# (~9.7 GiB per-op, ~13.3 GiB fused) and n = 100_000 for CL-ADMM
 # (~14.3 GiB); CL at n = 150_000 is refused.  Wake-ups per round are a
 # tenth of n in both.
 # phase 1: MP gossip

@@ -269,9 +269,9 @@ def batched_model_update(nbr_p_rows, K_rows, c_rows, sol_rows, alpha,
                   / (alpha + (1-alpha) c_i)
 
     This is THE per-shard step: the single-device scenario engine applies
-    it to rows of its global (n, k, p) state, the partitioned engine
-    (``simulate.partition``) to rows of each shard's local block, and the
-    dense references reach the same reduction through
+    it to the rows it gathers from its flat (n * k, p) slot table, the
+    partitioned engine (``simulate.partition``) to rows of each shard's
+    local block, and the dense references reach the same reduction through
     ``neighbor_aggregate`` — all dispatched through ``kernels.dispatch``,
     so the trajectories agree bit-for-bit whichever layout ran them.
     """

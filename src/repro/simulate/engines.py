@@ -5,6 +5,8 @@ State is O(n * k * p) instead of the reference engines' O(n^2 * p):
 
   theta (n, p)        — each agent's own model
   K     (n, k_max, p) — K[i, s] = agent i's copy of neighbor nbr_idx[i, s]
+                        (the per-op scenario round carries it flat,
+                        (n * k_max, p): see ``_scenario_scan``)
 
 Two operating modes:
 
@@ -248,7 +250,15 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
     first-receipt flags.  The caller passes the plain unshifted keys; the
     keys are shifted one round ahead internally so the carried prefetch
     consumes the bitwise-identical RNG stream.  At the default None the
-    traced program is the historic per-op gather/mix/scatter sequence.
+    traced program is the historic per-op gather/mix/scatter sequence,
+    over a slot table carried flat: ``(n * k_max, p)``, agent i's slot s
+    at row ``i * k_max + s``.  Its two slot scatters write that layout,
+    and the update gathers each waker's k_max rows from it.  On a TPU an
+    f32 array is tiled (8, 128) over its last two dims, so for a k_max
+    that is no multiple of 8 an ``(n, k_max, p)`` carry is no bitcast of
+    the scatters' layout: it was relaid out into it and back every round,
+    a copy of the whole table each way.  On the CPU the two layouts are
+    the same bytes and the trajectory is bit for bit the 3-D one's.
 
     Both round bodies build their carry (warm start included) here, in-jit,
     from ``theta_sol``: the ~n*k*p slot table is then a temporary the scan
@@ -284,8 +294,10 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
             carry0 = (theta0, Ke0, pf0, active0, jnp.int32(0), jnp.int32(0),
                       jnp.int32(0), jnp.zeros((n,), bool))
         else:
-            carry0 = (theta0, K0, theta0, jnp.ones((n,), bool),
-                      jnp.int32(0), jnp.int32(0), jnp.int32(0))
+            # the slot table flat, row i*km + s (see the docstring)
+            carry0 = (theta0, K0.reshape(n * km, -1), theta0,
+                      jnp.ones((n,), bool), jnp.int32(0), jnp.int32(0),
+                      jnp.int32(0))
         if tel:
             carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0),
                                jnp.int32(0), jnp.int32(0), jnp.int32(0))
@@ -343,23 +355,27 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
                                   theta[ev.i])
                 msg_j = jnp.where(ev.stale_ji[:, None], theta_prev[ev.j],
                                   theta[ev.j])
-                # undelivered messages scatter out of bounds -> dropped
+                # undelivered messages keep row n, so their flat row
+                # n*km + slot is out of bounds -> dropped
                 row_j = jnp.where(ev.deliver_ij, ev.j, n)
                 row_i = jnp.where(ev.deliver_ji, ev.i, n)
                 # scatter: last-write-wins — a repeated edge in one batch
                 # lands the batch-order winner; kernels/round_fuse.
                 # round_step dedups to the same winner so both paths agree
                 # bit-for-bit
-                K = K.at[row_j, ev.r].set(msg_i, mode="drop")
+                K = K.at[row_j * km + ev.r].set(msg_i, mode="drop")
                 # scatter: last-write-wins
-                K = K.at[row_i, ev.s].set(msg_j, mode="drop")
+                K = K.at[row_i * km + ev.s].set(msg_j, mode="drop")
 
             # --- update: endpoints that received a message recompute
             # Eq. (6) via the shared per-shard step
             # (core.sparse.batched_model_update — the same function the
             # partitioned engine applies to local rows)
             with spans.phase("update"):
-                new = batched_model_update(tabs.nbr_p[upd], K[upd], c[upd],
+                # the updaters' km rows each: (2B, km) flat indices
+                slots = upd[:, None] * km + jnp.arange(km)
+                K_upd = K[slots.reshape(-1)].reshape(*slots.shape, -1)
+                new = batched_model_update(tabs.nbr_p[upd], K_upd, c[upd],
                                            theta_sol[upd], alpha)
                 # scatter: idempotent — duplicate agents in upd recompute
                 # the same row from the same post-communication K
@@ -409,8 +425,8 @@ def _scenario_scan(tabs, part_half, rates, theta_sol, c, keys, ts, *,
             frac = jnp.mean(carry[3].astype(jnp.float32))
             if tel:
                 theta, K = carry[0], carry[1]
-                if fused:
-                    K = decode_slots(K, km)
+                K = decode_slots(K, km) if fused \
+                    else K.reshape(n, km, -1)
                 obj = tmetrics.mp_local_objective(theta, K, tabs.nbr_p, c,
                                                   theta_sol, alpha)
                 stale, updates, d_link, d_churn, d_part = \
@@ -436,7 +452,10 @@ def run_mp_scenario(topo: SparseTopology, theta_sol, c, alpha: float,
     the receivers' neighbor slots; stale deliveries read the sender's model
     from the previous round), then every endpoint that received something
     recomputes its model from its post-communication slots (update step
-    Eq. 6).  Inactive (churned-out) agents neither wake nor update.
+    Eq. 6).  Inactive (churned-out) agents neither wake nor update.  The
+    slots live in a flat ``(n * k_max, p)`` table, row ``i * k_max + s``
+    (the layout the scatters write, so a TPU's (8, 128) tiling does not
+    relay the table out and back each round; ``_scenario_scan``).
 
     The horizon is floored to a multiple of record_every (record_every is
     clamped to ``rounds`` first); SimTrace.rounds reports the actual count.

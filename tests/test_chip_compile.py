@@ -17,6 +17,7 @@ import functools
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,23 +89,88 @@ def _fits_one_chip(compiled):
     return total
 
 
+@pytest.fixture(scope="module")
+def mp_scan(one_chip):
+    """The MP scenario scan compiled at smoke size, once per backend."""
+    compiled = {}
+
+    def get(backend):
+        if backend not in compiled:
+            n, k, p, B = cs.MP_N, MP_KMAX, cs.P_DIM, cs.MP_BATCH
+            re_ = cs.RECORD_EVERY
+            n_rec = cs.MP_ROUNDS // re_
+            S = functools.partial(_abstract, one_chip)
+            cond = get_scenario("lossy-10").make_conditions(cs.MP_ROUNDS)
+            compiled[backend] = engines._scenario_scan.lower(
+                _tables(one_chip, n, k), S((n,), jnp.bool_), S((n,)),
+                S((n, p)), S((n,)), _keys(one_chip, n_rec, re_),
+                S((n_rec,), jnp.int32), conditions=cond, alpha=cs.ALPHA,
+                batch=B, record_every=re_, n_rec=n_rec, tel=False,
+                backend=backend).compile()
+        return compiled[backend]
+    return get
+
+
 @pytest.mark.parametrize("backend", [
     None, dispatch.ReproBackend.using(round_step="xla")],
     ids=["per_op", "fused_xla"])
-def test_mp_scenario_scan_compiles_at_smoke_size(one_chip, backend):
-    n, k, p, B = cs.MP_N, MP_KMAX, cs.P_DIM, cs.MP_BATCH
-    re_ = cs.RECORD_EVERY
-    n_rec = cs.MP_ROUNDS // re_
-    S = functools.partial(_abstract, one_chip)
-    cond = get_scenario("lossy-10").make_conditions(cs.MP_ROUNDS)
-    compiled = engines._scenario_scan.lower(
-        _tables(one_chip, n, k), S((n,), jnp.bool_), S((n,)), S((n, p)),
-        S((n,)), _keys(one_chip, n_rec, re_),
-        S((n_rec,), jnp.int32), conditions=cond, alpha=cs.ALPHA, batch=B,
-        record_every=re_, n_rec=n_rec, tel=False,
-        backend=backend).compile()
+def test_mp_scenario_scan_compiles_at_smoke_size(mp_scan, backend):
     # the slot table alone is n * k * p * 4 bytes
-    assert _fits_one_chip(compiled) > n * k * p * 4
+    assert _fits_one_chip(mp_scan(backend)) \
+        > cs.MP_N * MP_KMAX * cs.P_DIM * 4
+
+
+_HLO_OP = re.compile(r"%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\("
+                     r"(?:.*calls=%([\w.-]+))?")
+
+
+def _loop_body_ops(text):
+    """(name, opcode, element count, opcodes of the fused computation) of
+    every array-valued instruction at the top level of a while loop's
+    body in an optimized HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            cur = comps.setdefault(name, [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line.strip().removeprefix("ROOT "))
+    bodies = {b for lines in comps.values() for line in lines
+              for b in re.findall(r"body=%([\w.-]+)", line)}
+    ops = []
+    for body in sorted(bodies):
+        for line in comps[body]:
+            m = _HLO_OP.match(line)
+            if m is None:
+                continue
+            name, _, dims, opcode, calls = m.groups()
+            count = int(np.prod([int(d) for d in dims.split(",") if d]))
+            inner = {i.group(4) for i in map(_HLO_OP.match,
+                                             comps.get(calls, [])) if i}
+            ops.append((name, opcode, count, inner))
+    return ops
+
+
+def test_per_op_mp_round_writes_its_slot_table_only_by_scatter(mp_scan):
+    """On the chip an f32 array is tiled (8, 128) over its last two dims,
+    and k_max = 18 is no multiple of 8, so an (n, k_max, p) slot table is
+    no bitcast of the flat (n * k_max, p) layout its slot scatters write:
+    carried 3-D, it was relaid out into that layout and back every round,
+    a whole-table copy each way.  The per-op round carries it flat, so in
+    the loop bodies no reshape, copy or transpose outputs a table-sized
+    array, and the only ops that write one are the two slot scatters."""
+    size = cs.MP_N * MP_KMAX * cs.P_DIM
+    ops = _loop_body_ops(mp_scan(None).as_text())
+    assert ops, "no while loop body found"
+    writes = [(name, opcode, inner) for name, opcode, count, inner in ops
+              if count == size and opcode not in (
+                  "parameter", "get-tuple-element", "bitcast")]
+    relayouts = [name for name, opcode, _ in writes
+                 if opcode in ("reshape", "transpose")
+                 or opcode.startswith("copy")]
+    assert not relayouts, relayouts
+    assert len(writes) == 2, writes
+    assert all("scatter" in inner for _, _, inner in writes), writes
 
 
 def test_cl_scenario_scan_compiles_at_smoke_size(one_chip):

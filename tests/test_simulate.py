@@ -3,6 +3,8 @@ generators, fault scenarios, event-sampling edge cases (degree-0 agents,
 all-churned wake draws, the shared recording policy), and the edge-coloring
 matching property."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ from repro.simulate import (NetworkConditions, SparseTopology,
                             random_geometric_topology, ring_topology,
                             run_mp_scenario, sparse_async_admm,
                             sparse_async_gossip, sparse_sync_mp)
+from repro.telemetry.config import TelemetryConfig
 
 
 def isolated_agent_topology(n: int = 12, iso: int = 5) -> SparseTopology:
@@ -511,6 +514,47 @@ class TestAccountingInvariant:
             assert tr.dropped == 0 and tr.delivered == 2 * tr.events
         if name in ("drop", "partition", "all"):
             assert tr.dropped > 0
+
+
+class TestPerOpSlotTable:
+    """The per-op MP round (``backend=None``) carries its slot table flat,
+    ``(n * k_max, p)`` with row ``i * k_max + s`` for agent i's slot s; its
+    counters, snapshots and telemetry are bit for bit those of the
+    ``(n, k_max, p)`` layout it replaced, recorded in
+    ``tests/data/mp_lossy10_n3000.npz``.  Under lossy-10 at B = n / 2
+    every round lands 100-160 delivered messages on a (row, slot) that
+    another message of the batch also targets (k_max = 15 here), so the
+    scatters' last-write-wins order is held to the record too."""
+
+    RECORD = pathlib.Path(__file__).parent / "data" / "mp_lossy10_n3000.npz"
+
+    @staticmethod
+    def run(tel):
+        n, p, rounds = 3000, 16, 40
+        topo = random_geometric_topology(n, k=8, seed=0)
+        rng = np.random.default_rng(0)
+        sol = rng.standard_normal((n, p)).astype(np.float32)
+        c = rng.uniform(0.05, 1.0, n).astype(np.float32)
+        cond = get_scenario("lossy-10").make_conditions(rounds)
+        return run_mp_scenario(
+            topo, sol, c, 0.8, cond, rounds=rounds, batch=1500, seed=11,
+            record_every=20,
+            telemetry=TelemetryConfig(enabled=True) if tel else None)
+
+    @pytest.mark.parametrize("tel", [False, True], ids=["plain", "telemetry"])
+    def test_matches_recorded_3d_layout(self, tel):
+        want = np.load(self.RECORD)
+        tr = self.run(tel)
+        assert [tr.delivered, tr.dropped, tr.invalid, tr.events] \
+            == want["counters"].tolist()
+        np.testing.assert_array_equal(tr.theta_hist, want["theta_hist"])
+        np.testing.assert_array_equal(tr.active_hist, want["active_hist"])
+        if tel:
+            for name in ("objective", "staleness", "updates"):
+                np.testing.assert_array_equal(
+                    getattr(tr.telemetry, name), want[name])
+        else:
+            assert tr.telemetry is None
 
 
 # ---------------------------------------------------------------------------
