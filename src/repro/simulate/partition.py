@@ -104,7 +104,7 @@ def greedy_partition(topo: SparseTopology, n_shards: int, seed: int = 0,
                      refine_passes: int = 4) -> np.ndarray:
     """Greedy edge-cut assignment of agents to ``n_shards`` balanced shards.
 
-    Linear deterministic greedy (Stanton & Kleinberg): visit agents in BFS
+    Linear deterministic greedy (Stanton & Kliot, KDD 2012): visit agents in BFS
     order and put each on the shard holding most of its already-placed
     neighbors, discounted by shard fullness and hard-capped at
     ceil(n / P) agents; then ``refine_passes`` local passes move each agent
@@ -349,6 +349,24 @@ def default_local_events(batch: int, n_shards: int) -> int:
     return _binomial_cap(2 * batch, n_shards, batch)
 
 
+# The layout the sharded runners built last, with what it was built from:
+# a caller that makes many calls over one topology and assignment builds
+# it once (0.1 s at n = 200,000; the runners never write the tables).
+_last_layout: list = []
+
+
+def _layout(topo, assignment, P_) -> GraphPartition:
+    """``GraphPartition.build(topo, assignment, P_)``, reused while the
+    same topology object comes with an equal assignment."""
+    if _last_layout:
+        t, a, q, part = _last_layout
+        if t is topo and q == P_ and np.array_equal(a, assignment):
+            return part
+    part = GraphPartition.build(topo, assignment, P_)
+    _last_layout[:] = [topo, np.array(assignment), P_, part]
+    return part
+
+
 def _sharded_setup(topo, n_shards, mesh, assignment, partition_seed):
     """Shared preamble of the three sharded runners: resolve the mesh,
     the shard assignment (greedy by default, validated when explicit)
@@ -364,8 +382,7 @@ def _sharded_setup(topo, n_shards, mesh, assignment, partition_seed):
             f"has only {P_} devices (start the process with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=<P> for "
             f"fake host devices)")
-    part = GraphPartition.build(topo, assignment, P_)
-    return mesh, P_, assignment, part
+    return mesh, P_, assignment, _layout(topo, assignment, P_)
 
 
 def _on_shards(mesh, x):
@@ -373,6 +390,22 @@ def _on_shards(mesh, x):
     shard) on the mesh, each device receiving only its own block — never
     the whole array on one device first."""
     return jax.device_put(np.asarray(x), agent_sharding(mesh))
+
+
+@jax.jit
+def _stack_rows(x, ids):
+    """:meth:`GraphPartition.shard_rows` on the device: ``x[ids]`` with the
+    rows of negative ids zero."""
+    live = (ids >= 0).reshape((-1,) + (1,) * (x.ndim - 1))
+    return jnp.where(live, x[jnp.maximum(ids, 0)], 0)
+
+
+def _device_rows_on_shards(mesh, ids, x):
+    """Per-agent rows (n, ...) already on a device into the stacked
+    (P * m, ...) layout on the mesh (``ids``: the partition's flattened
+    ``local_ids``): permuted where they are, then each block moved to its
+    own device — never through the host."""
+    return jax.device_put(_stack_rows(x, ids), agent_sharding(mesh))
 
 
 def _partition_args(mesh, part: GraphPartition) -> dict:
@@ -666,7 +699,7 @@ def run_mp_scenario_sharded(topo: SparseTopology, theta_sol, c, alpha: float,
          static_argnames=("mesh", "mu", "rho", "k", "m", "H", "E", "U",
                           "n_rec", "record_every", "exchange", "codec",
                           "tel", "primal"))
-def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
+def _sharded_cl_scan(mesh, stream, theta0, nbr_idx, nbr_w, deg_count, D,
                      m_counts, sx, fetch, bnd_pos, halo_src_shard,
                      halo_src_pos,
                      tel_args=(), xym=(), *,
@@ -677,8 +710,13 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
     """shard_map'd CL-ADMM rounds: the six ADMM state arrays are row-sharded
     (P * m leading axis); the event stream is replicated and replayed per
     shard exactly as the MP engine does.  Only the warm-start models and
-    neighbour copies come in as arguments; each shard builds the rest of
-    its state in-jit (``engines.warm_admm_from_copies``).
+    each row's neighbour ids come in as arguments: before the first round
+    each shard exchanges its boundary models once (always in f32, so the
+    copies are exact whatever ``codec``), gathers its rows' neighbour
+    copies from that buffer through ``fetch`` and builds the rest of its
+    state in-jit (``engines.warm_admm_from_copies``).  Rows with no
+    neighbour (the layout's pad rows) read the zero row, as their copies
+    always were.
 
     Edge state never leaves its owner: for a cross-shard edge each endpoint
     shard keeps its own (Z_own, Z_nbr, L_own, L_nbr) slots and mirrors the
@@ -696,7 +734,7 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
     batch = stream.i.shape[-1]
     n_xym = 3 if (primal is not None and primal.needs_data) else 0
 
-    def block_fn(ev, theta0_blk, K0_blk, w_blk, degc_blk, D_blk, mc_blk,
+    def block_fn(ev, theta0_blk, nbr_blk, w_blk, degc_blk, D_blk, mc_blk,
                  sx_blk, fetch_blk, bnd_blk, hsrc_blk, hpos_blk,
                  *extra_blks):
         xym_blk = extra_blks[:n_xym]
@@ -706,6 +744,7 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
         hsrc, hpos = hsrc_blk[0], hpos_blk[0]
         exchange_halo = halo_exchange_fn(bnd, hsrc, hpos, H, P_, exchange,
                                          codec=codec)
+        exchange_models = halo_exchange_fn(bnd, hsrc, hpos, H, P_, exchange)
         with spans.phase("init"):
             live_blk = jnp.arange(k)[None, :] < degc_blk[:, None]  # (m, k)
 
@@ -820,8 +859,11 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
                     return carry, (carry[0], obj, stale, updates[None])
             return carry, carry[0]
 
+        with spans.phase("exchange"):
+            models0 = exchange_models(theta0_blk)          # (m + H + 1, p)
         with spans.phase("init"):
-            st0 = warm_admm_from_copies(theta0_blk, K0_blk)
+            src = jnp.where(degc_blk[:, None] > 0, fetch_q[nbr_blk], m + H)
+            st0 = warm_admm_from_copies(theta0_blk, models0[src])
         with spans.phase("exchange"):
             ext0 = publish(st0.theta, st0.K, st0.L_own, st0.L_nbr)  # warm
         with spans.phase("init"):
@@ -847,7 +889,7 @@ def _sharded_cl_scan(mesh, stream, theta0, K0, nbr_w, deg_count, D,
         in_specs=(_scan_specs(P(), ev_scan),) + (row,) * 7
         + (per_shard,) * 4 + (row,) * n_xym + (row,) * len(tel_args),
         out_specs=out_specs)
-    return run(ev_scan, theta0, K0, nbr_w, deg_count,
+    return run(ev_scan, theta0, nbr_idx, nbr_w, deg_count,
                D, m_counts, sx, fetch, bnd_pos, halo_src_shard,
                halo_src_pos, *xym, *tel_args)
 
@@ -909,47 +951,49 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
         else:
             batch = int(stream.i.shape[1])
 
-        # warm start (paper §4.2): the neighbour copies are gathered on
-        # the host (they cross shards); each shard derives the rest in-jit
-        theta0 = np.asarray(theta_sol, np.float32).reshape(topo.n, -1)
-        # the local-data reductions use the same jnp expressions as the
-        # single-device engine (numpy's pairwise summation rounds
-        # differently, which would break the bit-for-bit parity)
-        up += spans.upload_bytes(data.mask, data.x)
+        # warm start (paper §4.2): the models and the local-data
+        # reductions stay on the device (the reductions are the
+        # single-device engine's jnp expressions, so they round alike);
+        # each shard gathers its neighbour copies in-jit (_sharded_cl_scan)
+        up += spans.upload_bytes(theta_sol, data.mask, data.x,
+                                 part.local_ids)
+        theta0 = jnp.asarray(theta_sol, jnp.float32).reshape(topo.n, -1)
         mask = jnp.asarray(data.mask, jnp.float32)
         x = jnp.asarray(data.x, jnp.float32)
-        m_counts = np.asarray(jnp.sum(mask, axis=1))
-        sx = np.asarray(jnp.sum(x * mask[:, :, None], axis=1))
+        on_shards = partial(_device_rows_on_shards, mesh,
+                            jnp.asarray(part.local_ids.reshape(-1)))
         needs_data = primal is not None and primal.needs_data
         xym = ()
         if needs_data:
-            xym_rows = [part.shard_rows(np.asarray(a)) for a in
-                        (x, jnp.asarray(data.y, jnp.float32), mask)]
-            up += spans.upload_bytes(data.y, xym_rows)
-            xym = tuple(_on_shards(mesh, a) for a in xym_rows)
+            up += spans.upload_bytes(data.y)
+            xym = tuple(on_shards(a) for a in
+                        (x, jnp.asarray(data.y, jnp.float32), mask))
+        local = dict(theta0=on_shards(theta0),
+                     m_counts=on_shards(jnp.sum(mask, axis=1)),
+                     sx=on_shards(jnp.sum(x * mask[:, :, None], axis=1)))
         sharded = dict(
-            theta0=part.shard_rows(theta0),
-            K0=part.shard_rows(theta0[tabs.nbr_idx]),
+            nbr_idx=part.shard_rows(tabs.nbr_idx),
             nbr_w=part.shard_rows(tabs.nbr_w),
             deg_count=part.shard_rows(tabs.deg_count),
-            D=part.shard_rows(tabs.deg_w.astype(np.float32)),
-            m_counts=part.shard_rows(m_counts),
-            sx=part.shard_rows(sx))
+            D=part.shard_rows(tabs.deg_w.astype(np.float32)))
         E, U = _local_capacities(batch, P_, local_batch)
 
         tel = telemetry_on(telemetry)
         tel_args = ()
         if tel and not needs_data:
-            sxx = part.shard_rows(np.asarray(
-                jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1)))
-            up += spans.upload_bytes(sxx)
-            tel_args = (_on_shards(mesh, sxx),)
+            tel_args = (on_shards(
+                jnp.sum(mask * jnp.sum(x * x, axis=-1), axis=1)),)
         codec = resolve_halo_codec(halo_codec)
         args, up_rows = _upload(mesh, part, sharded)
         span.set_metadata(bytes=up + up_rows)
-    with spans.host("scan"):
+    row_bytes = codec.row_nbytes((1 + 3 * topo.k_max, theta0.shape[1]))
+    halo_bytes = halo_payload_bytes(P_, part.boundary_size, row_bytes,
+                                    part.halo_size)
+    with spans.host("scan", shards=P_, boundary_rows=part.boundary_size,
+                    halo_rows=part.halo_size, edge_cut=part.edge_cut,
+                    halo_bytes_per_round=halo_bytes):
         outs = _sharded_cl_scan(
-            mesh, stream, **args, tel_args=tel_args, xym=xym,
+            mesh, stream, **local, **args, tel_args=tel_args, xym=xym,
             mu=mu, rho=rho, k=topo.k_max, m=part.shard_size,
             H=part.halo_size, E=E, U=U, n_rec=n_rec,
             record_every=record_every, exchange=exchange, codec=codec,
@@ -964,11 +1008,9 @@ def run_cl_scenario_sharded(topo: SparseTopology, data, mu: float,
         frames = None
         if tel:
             hist, theta, overflow, obj_h, stale_h, upd_h = outs
-            p_dim = int(theta0.shape[1])
             frames = _sharded_frames(
                 part, stream, n_rec, record_every, obj_h, stale_h, upd_h,
-                overflow, payload_row_bytes=codec.row_nbytes(
-                    (1 + 3 * topo.k_max, p_dim)))
+                overflow, payload_row_bytes=row_bytes)
         else:
             hist, theta, overflow = outs
         return ShardedSimTrace(

@@ -235,7 +235,7 @@ def test_sharded_cl_scan_compiles_at_four_chip_size(topo, cl_partition,
             ("fetch", "bnd_pos", "halo_src_shard", "halo_src_pos")}
     E, U = _local_capacities(B, 4, None)
     compiled = _sharded_cl_scan.lower(
-        mesh, stream, R((Pm, p)), R((Pm, k, p)), R((Pm, k)),
+        mesh, stream, R((Pm, p)), R((Pm, k), jnp.int32), R((Pm, k)),
         R((Pm,), jnp.int32), R((Pm,)), R((Pm,)), R((Pm, p)), **halo,
         mu=cs.MU, rho=cs.RHO, k=k, m=part.shard_size, H=part.halo_size,
         E=E, U=U, n_rec=rounds // re_, record_every=re_,

@@ -143,6 +143,17 @@ class TestGraphPartitionLayout:
             .astype(np.float32)
         assert np.array_equal(part.unshard_rows(part.shard_rows(x)), x)
 
+    def test_layout_reused_for_the_same_topology_and_assignment(self):
+        """A caller making many calls over one topology and assignment
+        builds the layout once; another topology object rebuilds it."""
+        from repro.simulate.partition import _sharded_setup
+        topo = random_geometric_topology(120, k=4, seed=5)
+        a = greedy_partition(topo, 1)
+        part = _sharded_setup(topo, 1, None, a, 0)[3]
+        assert _sharded_setup(topo, 1, None, a.copy(), 0)[3] is part
+        other = random_geometric_topology(120, k=4, seed=5)
+        assert _sharded_setup(other, 1, None, a, 0)[3] is not part
+
     def test_capacity_heuristics(self):
         assert default_local_batch(100, 1) == 200
         assert default_local_events(100, 1) == 100
@@ -442,6 +453,83 @@ SUBPROC = textwrap.dedent("""
                                   theta_sol=sol4)
     assert sh4.n_shards == 8 and sh4.overflow == 0
     assert np.abs(sh4.theta_hist - cl4.theta_hist).max() == 0.0
+
+    # the on-device warm start (each shard gathers its neighbour copies
+    # from one exchange of the models) is the single-device one, bit for
+    # bit, under churn alone and under loss with staleness
+    for cond5 in (NetworkConditions(churn_rate=0.02),
+                  NetworkConditions(drop_prob=0.2, stale_prob=0.4)):
+        cl5 = run_cl_scenario(topo, data, 0.1, 1.0, cond5, rounds=30,
+                              batch=32, seed=7, record_every=10,
+                              theta_sol=soln)
+        sh5 = run_cl_scenario_sharded(topo, data, 0.1, 1.0, cond5,
+                                      rounds=30, batch=32, seed=7,
+                                      record_every=10, theta_sol=soln)
+        assert sh5.overflow == 0, cond5
+        assert np.array_equal(sh5.theta_hist, cl5.theta_hist), cond5
+
+    # an isolated agent: its row of copies (never read) may differ from
+    # the single-device one, and the trajectory does not
+    from repro.core.sparse import tables_from_adjacency
+    from repro.simulate import SparseTopology
+    ring = [v for v in range(60) if v % 15 != 7]    # 7, 22, 37, 52 alone
+    rows = [np.array([], np.int64)] * 60
+    for a, v in enumerate(ring):
+        rows[v] = np.sort([ring[a - 1], ring[(a + 1) % len(ring)]])
+    topo6 = SparseTopology(
+        tables_from_adjacency(rows, [np.ones(len(r)) for r in rows],
+                              allow_isolated=True),
+        (np.arange(60) >= 30).astype(np.int32))
+    xs6 = [rng.standard_normal((3, 4)) for _ in range(60)]
+    data6 = pad_datasets(xs6, [np.zeros(3)] * 60)
+    sol6 = np.asarray(solitary_mean(data6), np.float32)
+    kw6 = dict(rounds=20, batch=10, seed=2, record_every=10,
+               theta_sol=sol6)
+    cl6 = run_cl_scenario(topo6, data6, 0.1, 1.0, cond, **kw6)
+    sh6 = run_cl_scenario_sharded(topo6, data6, 0.1, 1.0, cond,
+                                  n_shards=4, **kw6)
+    assert sh6.overflow == 0 and sh6.halo_size > 0
+    assert np.array_equal(sh6.theta_hist, cl6.theta_hist)
+
+    # under the profiler: a sharded CL call uploads the tables, the host
+    # inputs and the partition's tables, and no (n, k_max, p) array of
+    # neighbour copies; its scan span states the partition's sizes
+    import glob, gzip, json, tempfile
+    from repro.core.losses import AgentData
+    from repro.launch.sim_mesh import halo_payload_bytes
+    from repro.simulate import GraphPartition, ScenarioSpec, greedy_partition
+    from repro.simulate import run_scenario
+    host = AgentData(*(np.asarray(a) for a in (data.x, data.y, data.mask)))
+    trace_dir = tempfile.mkdtemp()
+    jax.profiler.start_trace(trace_dir)
+    run_scenario(ScenarioSpec(
+        algo="cl", topology=topo, data=host, mu=0.1, rho=1.0,
+        theta_sol=soln, conditions=cond, rounds=10, batch=16, seed=1,
+        record_every=5, sharded=True))
+    jax.profiler.stop_trace()
+    path, = glob.glob(trace_dir + "/plugins/profile/*/*.trace.json.gz")
+    with gzip.open(path, "rt") as f:
+        ev = {e["name"]: e.get("args", {}) for e in json.load(f)["traceEvents"]
+              if e.get("ph") == "X" and e["name"].startswith("repro/host/")}
+    part = GraphPartition.build(topo, greedy_partition(topo, 8), 8)
+    n, k, p = topo.n, topo.k_max, soln.shape[1]
+    Pm = 8 * part.shard_size
+    tabs = [np.asarray(a) for a in topo.tables]
+    up = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+             for a in tabs + [topo.partition_halves(), soln,
+                                       np.asarray(host.x, np.float32),
+                                       np.asarray(host.mask, np.float32),
+                                       part.local_ids, part.fetch,
+                                       part.bnd_pos, part.halo_src_shard,
+                                       part.halo_src_pos])
+    up += 4 * (2 * Pm * k + 2 * Pm)     # the shard-stacked (Pm, k) and (Pm,)
+    assert int(ev["repro/host/prepare"]["bytes"]) == up, (ev, up)
+    args = {a: int(v) for a, v in ev["repro/host/scan"].items()}
+    assert args == dict(
+        shards=8, boundary_rows=part.boundary_size,
+        halo_rows=part.halo_size, edge_cut=part.edge_cut,
+        halo_bytes_per_round=halo_payload_bytes(
+            8, part.boundary_size, 4 * (1 + 3 * k) * p, part.halo_size))
     print("SHARDED-8DEV-OK")
 """)
 
