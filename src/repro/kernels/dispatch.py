@@ -495,9 +495,13 @@ def _round_step_pallas(interpret):
 # ---------------------------------------------------------------------------
 # cl_edge_step — one fused CL-ADMM edge phase (scenario-engine semantics):
 #   (theta (n,p), K (n,k,p), Z_own, Z_nbr, L_own, L_nbr (n,k,p),
-#    pv_th (n,p), pv_K/pv_Lo/pv_Ln (n,k,p) publish snapshot,
+#    pv_th/pv_K/pv_Lo/pv_Ln (E,p) previous round's publish at the partner
+#    cells, f_Lo/f_Ln (E,p) round-start partner duals,
 #    upd/own_s/oth_a/oth_s (E,) int32, stale/got (E,) bool, *, rho)
 #   -> (Z_own', Z_nbr', L_own', L_nbr')
+# The payload rows come from ``simulate.engines._cl_scenario_scan``, which
+# gathers them after the scatters of the rounds before, so no whole-state
+# snapshot stays live across a round.
 # ---------------------------------------------------------------------------
 
 

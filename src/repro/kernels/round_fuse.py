@@ -58,10 +58,11 @@ tests/test_round_fuse.py).
 Whole-state-in-VMEM is the operating point, and it bounds both kernels to
 small states.  The v5e compiler (tests/test_chip_compile.py) accepts
 ``round_step_pallas`` at n=1024, k=8, p=64 and ``cl_edge_step_pallas`` at
-n=1024, k=8, p=64 (17 MB of operands); it refuses n=10,000, k=8, p=32 for
-``round_step`` (39 MB of scoped VMEM against its 16 MiB limit) and
-n=4096, k=8, p=32 for ``cl_edge_step`` (86 MB).  Larger states belong to
-the fused-XLA impl — the auto choice — or the sharded engines.
+n=2048, k=8, p=64 (22 MB of operands: its payload arrives as event rows);
+it refuses n=10,000, k=8, p=32 for ``round_step`` (39 MB of scoped VMEM
+against its 16 MiB limit) and n=4096, k=8, p=32 for ``cl_edge_step`` (36
+MB).  Larger states belong to the fused-XLA impl — the auto choice — or
+the sharded engines.
 """
 
 from __future__ import annotations
@@ -376,15 +377,20 @@ def round_step_pallas(theta, Ke, got_ever, msg, tgt_row, enc, k_old,
 
 
 def cl_edge_step(theta, K, Z_own, Z_nbr, L_own, L_nbr,
-                 pv_th, pv_K, pv_Lo, pv_Ln,
+                 pv_th, pv_K, pv_Lo, pv_Ln, f_Lo, f_Ln,
                  upd, own_s, oth_a, oth_s, stale, got, *, rho: float):
     """One batched CL-ADMM edge phase (scenario-engine semantics).
 
-    theta (n, p) / K (n, k, p) are *post-primal*; Z/L slot arrays and the
-    previous-round publish snapshot ``pv_*`` are round-start.  Per event
-    side e: agent ``upd[e]`` updates its slot ``own_s[e]`` from partner
-    ``oth_a[e]``'s payload (slot ``oth_s[e]``; ``stale`` selects the
-    snapshot), scattered only where ``got`` (OOB-dropped otherwise).
+    theta (n, p) / K (n, k, p) are *post-primal*; Z/L slot arrays are
+    round-start.  Per event side e: agent ``upd[e]`` updates its slot
+    ``own_s[e]`` from partner ``oth_a[e]``'s payload (slot ``oth_s[e]``),
+    scattered only where ``got`` (OOB-dropped otherwise).  The payload's
+    dual rows arrive as (E, p) event rows, gathered by the caller: ``f_Lo``
+    / ``f_Ln`` are the round-start ``L_own`` / ``L_nbr`` at the partner
+    cells, and ``pv_*`` the previous round's publish (post-primal theta and
+    K, round-start duals) at the same cells, selected where ``stale``.
+    Only the fresh models ``theta[oth_a]`` / ``K[oth_a, oth_s]`` are
+    gathered here: the edge phase never writes them.
 
     The expressions mirror ``simulate.engines._cl_scenario_scan`` line for
     line (payload selection, ``core.sparse.admm_edge_halfstep`` math, four
@@ -396,10 +402,10 @@ def cl_edge_step(theta, K, Z_own, Z_nbr, L_own, L_nbr,
     """
     n = theta.shape[0]
     stale_c = stale[:, None]
-    th_pay = jnp.where(stale_c, pv_th[oth_a], theta[oth_a])
-    k_pay = jnp.where(stale_c, pv_K[oth_a, oth_s], K[oth_a, oth_s])
-    lo_pay = jnp.where(stale_c, pv_Lo[oth_a, oth_s], L_own[oth_a, oth_s])
-    ln_pay = jnp.where(stale_c, pv_Ln[oth_a, oth_s], L_nbr[oth_a, oth_s])
+    th_pay = jnp.where(stale_c, pv_th, theta[oth_a])
+    k_pay = jnp.where(stale_c, pv_K, K[oth_a, oth_s])
+    lo_pay = jnp.where(stale_c, pv_Lo, f_Lo)
+    ln_pay = jnp.where(stale_c, pv_Ln, f_Ln)
     theta_own = theta[upd]
     k_own = K[upd, own_s]
     l_own = L_own[upd, own_s]
@@ -410,18 +416,25 @@ def cl_edge_step(theta, K, Z_own, Z_nbr, L_own, L_nbr,
     z_nbr = 0.5 * ((lo_pay + l_nbr) / rho + th_pay + k_own)
     lo_new = l_own + rho * (theta_own - z_own)
     ln_new = l_nbr + rho * (k_own - z_nbr)
-    rowu = jnp.where(got, upd, n)
-    # scatter: unique targets — each event side writes its own (agent, slot)
-    # cell; a slot belongs to one edge and each edge fires once per round
-    Z_own = Z_own.at[rowu, own_s].set(z_own, mode="drop")
-    Z_nbr = Z_nbr.at[rowu, own_s].set(z_nbr, mode="drop")  # scatter: unique targets
-    L_own = L_own.at[rowu, own_s].set(lo_new, mode="drop")  # scatter: unique targets
-    L_nbr = L_nbr.at[rowu, own_s].set(ln_new, mode="drop")  # scatter: unique targets
-    return Z_own, Z_nbr, L_own, L_nbr
+    # the scatters index the (n·k, p) view by row i·k + s: the TPU compiler
+    # lowers a (row, slot) scatter on the (n, k, p) array by carrying it
+    # slot-major, which relays the whole state out and back every round
+    # (undelivered sides ride at rows >= n·k and are dropped)
+    k, p = K.shape[1:]
+    enc = jnp.where(got, upd, n) * k + own_s
+
+    def put(a, v):
+        # scatter: unique targets — each event side writes its own
+        # (agent, slot) cell; a slot belongs to one edge and each edge
+        # fires once per round
+        a = a.reshape(n * k, p).at[enc].set(v, mode="drop")
+        return a.reshape(n, k, p)
+    return (put(Z_own, z_own), put(Z_nbr, z_nbr), put(L_own, lo_new),
+            put(L_nbr, ln_new))
 
 
 def _cl_edge_kernel(theta_ref, K_ref, Zo_ref, Zn_ref, Lo_ref, Ln_ref,
-                    pth_ref, pK_ref, pLo_ref, pLn_ref,
+                    pth_ref, pK_ref, pLo_ref, pLn_ref, fLo_ref, fLn_ref,
                     av, sv, ov, tv, stv, gv,
                     Zo_o, Zn_o, Lo_o, Ln_o,
                     zo_scr, zn_scr, lo_scr, ln_scr, *,
@@ -447,14 +460,14 @@ def _cl_edge_kernel(theta_ref, K_ref, Zo_ref, Zn_ref, Lo_ref, Ln_ref,
             o = ov[e, 0]
             ot = tv[e, 0]
             stl = stv[e, 0] != 0
-            th_pay = jnp.where(stl, _load_row(pth_ref, o),
+            th_pay = jnp.where(stl, _load_row(pth_ref, e),
                                _load_row(theta_ref, o))
-            k_pay = jnp.where(stl, _load_slot(pK_ref, o, ot),
+            k_pay = jnp.where(stl, _load_row(pK_ref, e),
                               _load_slot(K_ref, o, ot))
-            lo_pay = jnp.where(stl, _load_slot(pLo_ref, o, ot),
-                               _load_slot(Lo_ref, o, ot))
-            ln_pay = jnp.where(stl, _load_slot(pLn_ref, o, ot),
-                               _load_slot(Ln_ref, o, ot))
+            lo_pay = jnp.where(stl, _load_row(pLo_ref, e),
+                               _load_row(fLo_ref, e))
+            ln_pay = jnp.where(stl, _load_row(pLn_ref, e),
+                               _load_row(fLn_ref, e))
             theta_own = _load_row(theta_ref, a)
             k_own = _load_slot(K_ref, a, so)
             l_own = _load_slot(Lo_ref, a, so)
@@ -486,7 +499,7 @@ def _cl_edge_kernel(theta_ref, K_ref, Zo_ref, Zn_ref, Lo_ref, Ln_ref,
 
 @functools.partial(jax.jit, static_argnames=("rho", "block_b", "interpret"))
 def cl_edge_step_pallas(theta, K, Z_own, Z_nbr, L_own, L_nbr,
-                        pv_th, pv_K, pv_Lo, pv_Ln,
+                        pv_th, pv_K, pv_Lo, pv_Ln, f_Lo, f_Ln,
                         upd, own_s, oth_a, oth_s, stale, got, *,
                         rho: float, block_b: int = 128,
                         interpret: bool = False):
@@ -495,7 +508,8 @@ def cl_edge_step_pallas(theta, K, Z_own, Z_nbr, L_own, L_nbr,
     Grid ``(2, n_event_blocks)``: phase 0 computes every half-step from
     round-start state into VMEM scratch, phase 1 lands the masked scatters —
     the same all-reads-before-any-write barrier the XLA form gets from
-    functional updates.
+    functional updates.  The six payload row arrays are tiled
+    ``(block_b, p)`` per grid step like the event columns.
     """
     n, k, p = K.shape
     E = upd.shape[0]
@@ -509,20 +523,29 @@ def cl_edge_step_pallas(theta, K, Z_own, Z_nbr, L_own, L_nbr,
             x = jnp.concatenate([x, jnp.zeros((pad,), jnp.int32)])
         return x.reshape(-1, 1)
 
+    def mat(x):
+        x = jnp.asarray(x, jnp.float32)
+        if pad:
+            x = jnp.concatenate(
+                [x, jnp.zeros((pad, x.shape[1]), jnp.float32)])
+        return x
+
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     full = lambda a: pl.BlockSpec(a.shape, lambda ph, bi, _nd=a.ndim:
                                   (0,) * _nd)
     ev_spec = pl.BlockSpec((block_b, 1), lambda ph, bi: (bi, 0))
+    row_spec = pl.BlockSpec((block_b, p), lambda ph, bi: (bi, 0))
     args = (f32(theta), f32(K), f32(Z_own), f32(Z_nbr), f32(L_own),
-            f32(L_nbr), f32(pv_th), f32(pv_K), f32(pv_Lo), f32(pv_Ln),
-            col(upd), col(own_s), col(oth_a), col(oth_s), col(stale),
-            col(got))
+            f32(L_nbr), mat(pv_th), mat(pv_K), mat(pv_Lo), mat(pv_Ln),
+            mat(f_Lo), mat(f_Ln), col(upd), col(own_s), col(oth_a),
+            col(oth_s), col(stale), col(got))
     kernel = functools.partial(_cl_edge_kernel, rho=rho, block_b=block_b)
     slot_shape = jax.ShapeDtypeStruct((n, k, p), jnp.float32)
     return pl.pallas_call(
         kernel,
         grid=(2, nb),
-        in_specs=[full(a) for a in args[:10]] + [ev_spec] * 6,
+        in_specs=[full(a) for a in args[:6]] + [row_spec] * 6
+        + [ev_spec] * 6,
         out_specs=[full(K)] * 4,
         out_shape=[slot_shape] * 4,
         scratch_shapes=[pltpu.VMEM((E + pad, p), jnp.float32)] * 4,

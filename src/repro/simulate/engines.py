@@ -705,9 +705,18 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
        (``core.sparse.batched_admm_primal``) and rewrites theta + its K row.
        Duplicate endpoints in a batch read identical round-start state and
        scatter identical values, so collisions are deterministic.
-    2. **publish** — the round's payload snapshot is (post-primal theta and
-       K, round-start duals); the previous round's snapshot serves the
+    2. **publish** — the round's payload is (post-primal theta and K,
+       round-start duals); the previous round's payload serves the
        one-round-stale deliveries (same convention as the MP engine).
+       The stream fixes every round's partner cells ``(oth_a, oth_s)``
+       before the scan, so the carry holds only the (2B, p) payload rows
+       the next rounds read, never a whole-state snapshot: round t
+       gathers round t+1's stale model rows after its primal scatters,
+       and after its edge scatters round t+1's fresh dual rows and round
+       t+2's stale ones (round t+1's round-start duals, carried one round
+       more).  A snapshot, or a partner read of L_own / L_nbr in the round
+       that scatters them, held live across those scatters would make XLA
+       copy the whole (n, k, p) arrays every round.
     3. **edge phase** — each delivered direction updates the *receiver's*
        (Z_own, Z_nbr, L_own, L_nbr) slots via the fused ``cl_edge_step``
        op (kernels/round_fuse.py; the ``admm_edge_halfstep`` math) from
@@ -739,9 +748,18 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
         with spans.phase("init"):
             state0 = _warm_admm_state(*state0)
     edge_fn = resolve("cl_edge_step", backend)
+    # each round's partner cells, and those of the two rounds after it (the
+    # last rounds' lookahead wraps to round 0 and is never read)
+    n_rec, re_ = ev.i.shape[:2]
+    cells = [jnp.concatenate([a, b], axis=-1).reshape(n_rec * re_, -1)
+             for a, b in ((ev.j, ev.i), (ev.r, ev.s))]
+    ahead = tuple(jnp.roll(c, -d, axis=0).reshape(n_rec, re_, -1)
+                  for d in (1, 2) for c in cells)   # (a1, s1, a2, s2)
 
-    def round_fn(carry, ev_t):
-        st, pub_prev, *tstate = carry
+    def round_fn(carry, xs):
+        ev_t, (a1, s1, a2, s2) = xs
+        st, pay, *tstate = carry
+        pv_th, pv_K, pv_Lo, pv_Ln, f_Lo, f_Ln, nx_Lo, nx_Ln = pay
         # --- primal phase: endpoints whose incoming payload was delivered
         with spans.phase("primal"):
             upd = jnp.concatenate([ev_t.i, ev_t.j])                # (2B,)
@@ -764,9 +782,8 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
             # identical rows from the same round-start Z/L state
             theta = st.theta.at[rowu].set(new_theta, mode="drop")
             K = st.K.at[rowu].set(new_K, mode="drop")  # scatter: idempotent
-
-        # --- publish: post-primal models, round-start duals
-        pub = (theta, K, st.L_own, st.L_nbr)
+            # publish: the post-primal models are round t+1's stale rows
+            pub_th, pub_K = theta[a1], K[a1, s1]
 
         # --- edge phase: one half-step per delivered direction, as one
         # fused op (kernels/round_fuse.cl_edge_step — auto resolves the
@@ -778,8 +795,13 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
             oth_s = jnp.concatenate([ev_t.r, ev_t.s])
             stale = jnp.concatenate([ev_t.stale_ji, ev_t.stale_ij])
             Z_own, Z_nbr, L_own, L_nbr = edge_fn(
-                theta, K, st.Z_own, st.Z_nbr, st.L_own, st.L_nbr, *pub_prev,
+                theta, K, st.Z_own, st.Z_nbr, st.L_own, st.L_nbr,
+                pv_th, pv_K, pv_Lo, pv_Ln, f_Lo, f_Ln,
                 upd, own_s, oth_a, oth_s, stale, got, rho=rho)
+            # the post-edge duals are round t+1's round-start duals: its
+            # fresh rows, and round t+2's stale ones
+            pay = (pub_th, pub_K, nx_Lo, nx_Ln, L_own[a1, s1],
+                   L_nbr[a1, s1], L_own[a2, s2], L_nbr[a2, s2])
 
         st = SparseADMMState(theta, K, Z_own, Z_nbr, L_own, L_nbr)
         if tel:
@@ -788,10 +810,10 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
                 stale = tmetrics.staleness_step(stale, got, upd, n)
                 updates = updates + jnp.sum(got)
                 tstate = (stale, updates)
-        return (st, pub, *tstate), None
+        return (st, pay, *tstate), None
 
-    def outer(carry, ev_blk):
-        carry, _ = jax.lax.scan(round_fn, carry, ev_blk)
+    def outer(carry, xs):
+        carry, _ = jax.lax.scan(round_fn, carry, xs)
         st = carry[0]
         if tel:
             with spans.phase("record"):
@@ -810,11 +832,18 @@ def _cl_scenario_scan(nbr_w, deg_count, D, m_counts, sx, state0, ev,
         return carry, st.theta
 
     with spans.phase("init"):
-        pub0 = (state0.theta, state0.K, state0.L_own, state0.L_nbr)
-        carry0 = (state0, pub0)
+        # the warm start publishes state0: round 0's stale and fresh rows
+        # and round 1's stale duals all read it
+        a0, s0 = (c[0] for c in cells)
+        a1, s1 = (c[0, 0] for c in ahead[:2])
+        pay0 = (state0.theta[a0], state0.K[a0, s0], state0.L_own[a0, s0],
+                state0.L_nbr[a0, s0], state0.L_own[a0, s0],
+                state0.L_nbr[a0, s0], state0.L_own[a1, s1],
+                state0.L_nbr[a1, s1])
+        carry0 = (state0, pay0)
         if tel:
             carry0 = carry0 + (jnp.zeros((n,), jnp.int32), jnp.int32(0))
-    carry, hist = jax.lax.scan(outer, carry0, ev)
+    carry, hist = jax.lax.scan(outer, carry0, (ev, ahead))
     return carry[0], hist
 
 
@@ -893,7 +922,11 @@ def run_cl_scenario(topo: SparseTopology, data: AgentData, mu: float,
             tel_args = (sxx,)
 
         ev = _reshape_stream(stream, n_rec, record_every)
-    with spans.host("scan"):
+    p = (state.theta if isinstance(state, SparseADMMState)
+         else state[1]).shape[1]
+    # the scan carries 8 payload row arrays of (2B, p) f32 (_cl_scenario_scan)
+    with spans.host("scan", payload_rows=2 * batch,
+                    payload_carry_bytes=8 * 2 * batch * p * 4):
         st, hist = _cl_scenario_scan(
             tabs.nbr_w, tabs.deg_count, D, m_counts, sx, state, ev,
             tel_args, xym, mu=mu, rho=rho, backend=backend, tel=tel,

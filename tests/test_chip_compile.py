@@ -193,8 +193,43 @@ def test_cl_scenario_scan_compiles_at_smoke_size(one_chip):
         tabs.nbr_w, tabs.deg_count, S((n,)), S((n,)), S((n, p)), state0, ev,
         (S((n,)),), (), mu=cs.MU, rho=cs.RHO, backend=None, tel=True,
         primal=None).compile()
-    # eight (n, k, p) f32 arrays: five of state, three publish snapshots
-    assert _fits_one_chip(compiled) > 8 * n * k * p * 4
+    # at least the five (n, k, p) f32 arrays of state, each tiled (8, 128)
+    # on its last two dims (the round carries no whole-state snapshot)
+    tiled = n * -(-k // 8) * 8 * -(-p // 128) * 128 * 4
+    assert _fits_one_chip(compiled) > 5 * tiled
+
+
+def test_cl_round_loop_copies_no_whole_state(one_chip):
+    """At the one-chip CL cell's widths (k_max = 16, p = 100; n cut to
+    20,000 to compile in seconds) no computation of the v5e program but its
+    entry copies an (n, k_max, p) state array or a relaid view of one: the
+    round carries partner rows, not publish snapshots held across the
+    scatters, and the edge scatters write the (n·k, p) view in place (a
+    (row, slot) scatter makes the compiler carry the state slot-major and
+    relay it every round)."""
+    n, k, p, B, re_ = 20_000, 16, 100, 2_000, 25
+    S = functools.partial(_abstract, one_chip)
+    cond = get_scenario("churn-5").make_conditions(2 * re_)
+    tabs = _tables(one_chip, n, k)
+    stream = jax.eval_shape(
+        functools.partial(scheduler._draw_stream, conditions=cond, batch=B,
+                          rounds=2 * re_),
+        tabs, S((n,), jnp.bool_), S((n,)), S((2 * re_, 2), jnp.uint32))
+    ev = jax.tree_util.tree_map(
+        lambda a: S((2, re_) + a.shape[1:], a.dtype),
+        stream._replace(active_frac=None))
+    hlo = engines._cl_scenario_scan.lower(
+        tabs.nbr_w, tabs.deg_count, S((n,)), S((n,)), S((n, p)),
+        (tabs.nbr_idx, S((n, p))), ev, mu=cs.MU,
+        rho=cs.RHO).compile().as_text()
+    whole = {f"f32[{n},{k},{p}]", f"f32[{p},{k},{n}]", f"f32[{k},{n},{p}]",
+             f"f32[{n * k},{p}]", f"f32[{p},{n * k}]"}
+    copies = [line.strip()[:120]
+              for comp in re.split(r"\n(?=\S)", hlo)
+              if not comp.startswith("ENTRY") for line in comp.splitlines()
+              if (m := re.search(r"= (f32\[[\d,]+\])\S* copy\(", line))
+              and m.group(1) in whole]
+    assert " while(" in hlo and not copies, copies
 
 
 @pytest.fixture(scope="module")
@@ -272,8 +307,8 @@ def _pallas_cases(sh):
              S((nk,)))),
         ("cl_edge_step", "pallas"): (
             functools.partial(make("cl_edge_step", "pallas"), rho=1.0),
-            (S((1024, p)), slots, slots, slots, slots, slots, S((1024, p)),
-             slots, slots, slots, S((E,), i32), S((E,), i32), S((E,), i32),
+            (S((1024, p)), slots, slots, slots, slots, slots,
+             *[S((E, p))] * 6, S((E,), i32), S((E,), i32), S((E,), i32),
              S((E,), i32), S((E,), jnp.bool_), S((E,), jnp.bool_))),
         ("attention", "pallas"): (make("attention", "pallas"), (q, q, q)),
     }

@@ -94,9 +94,10 @@ def _make_args(op, seed=0):
         n, k, p, E = 23, 4, 10, 18
         arr3 = lambda: jnp.asarray(rng.standard_normal((n, k, p)), f32)
         arr2 = lambda: jnp.asarray(rng.standard_normal((n, p)), f32)
+        rows = lambda: jnp.asarray(rng.standard_normal((E, p)), f32)
         codes = rng.choice(n * k, size=E, replace=False)  # collision-free
         return (arr2(), arr3(), arr3(), arr3(), arr3(), arr3(),
-                arr2(), arr3(), arr3(), arr3(),
+                rows(), rows(), rows(), rows(), rows(), rows(),
                 jnp.asarray(codes // k, jnp.int32),
                 jnp.asarray(codes % k, jnp.int32),
                 jnp.asarray(rng.integers(0, n, E), jnp.int32),

@@ -256,8 +256,10 @@ class TestClEdgeStepPallas:
         f32 = jnp.float32
         a3 = lambda: jnp.asarray(rng.standard_normal((n, k, p)), f32)
         a2 = lambda: jnp.asarray(rng.standard_normal((n, p)), f32)
+        rows = lambda: jnp.asarray(rng.standard_normal((E, p)), f32)
         codes = rng.choice(n * k, size=E, replace=False)
-        args = (a2(), a3(), a3(), a3(), a3(), a3(), a2(), a3(), a3(), a3(),
+        args = (a2(), a3(), a3(), a3(), a3(), a3(),
+                rows(), rows(), rows(), rows(), rows(), rows(),
                 jnp.asarray(codes // k, jnp.int32),
                 jnp.asarray(codes % k, jnp.int32),
                 jnp.asarray(rng.integers(0, n, E), jnp.int32),

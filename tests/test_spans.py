@@ -253,7 +253,8 @@ def test_cl_call_fetches_stream_totals_while_the_scan_runs(tmp_path,
     """CL: the stream's totals are copied back between the scan's dispatch
     and the wait, in a fetch span of their own; the upload is the tables,
     the partition halves and the host data; the second fetch is the
-    snapshots."""
+    snapshots.  The scan span states the payload rows the round carries:
+    2B partner rows in each of eight (2B, p) f32 arrays."""
     topo, data, sol, _ = problem
     tr, spans = _traced(tmp_path, _spec(problem, "cl"))
     _, steps = _children(spans)
@@ -266,6 +267,8 @@ def test_cl_call_fetches_stream_totals_while_the_scan_runs(tmp_path,
     rounds, batch = RUN_KW["rounds"], RUN_KW["batch"]
     assert int(steps[2][1]["bytes"]) == 3 * rounds * batch + 4 * rounds
     assert int(steps[4][1]["bytes"]) == tr.theta_hist.nbytes
+    assert steps[1][1] == dict(payload_rows=str(2 * batch),
+                               payload_carry_bytes=str(8 * 2 * batch * 3 * 4))
 
 
 def test_serve_span_follows_the_engine(tmp_path, problem):
